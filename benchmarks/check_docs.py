@@ -27,8 +27,9 @@ import sys
 
 DOC_FILES = ("README.md", "docs/architecture.md", "docs/serving.md",
              "docs/analysis.md", "docs/resilience.md")
-# trees searched for flag definitions/uses
+# trees (and root-level scripts) searched for flag definitions/uses
 FLAG_TREES = ("src", "benchmarks", "examples", "tests", ".github", "results")
+FLAG_FILES = ("chip_smoke.py",)
 PATH_SUFFIXES = (".py", ".md", ".json", ".yml", ".yaml", ".txt", ".toml")
 GENERATED = re.compile(r"^(BENCH_\w+|analysis_report)\.json$")
 BACKTICK = re.compile(r"`([^`\n]+)`")
@@ -58,20 +59,19 @@ def _span_refs(span):
 
 def _flag_corpus(root):
     """Every ``--flag`` literal defined or used under the repo trees."""
-    flags = set()
+    paths = [os.path.join(root, name) for name in FLAG_FILES]
     for tree in FLAG_TREES:
-        top = os.path.join(root, tree)
-        for dirpath, _, names in os.walk(top):
-            for name in names:
-                if not name.endswith((".py", ".yml", ".yaml", ".sh")):
-                    continue
-                try:
-                    with open(os.path.join(dirpath, name),
-                              errors="ignore") as f:
-                        text = f.read()
-                except OSError:
-                    continue
-                flags.update(re.findall(r"--[a-z][a-z0-9-]*", text))
+        for dirpath, _, names in os.walk(os.path.join(root, tree)):
+            paths.extend(os.path.join(dirpath, name) for name in names
+                         if name.endswith((".py", ".yml", ".yaml", ".sh")))
+    flags = set()
+    for path in paths:
+        try:
+            with open(path, errors="ignore") as f:
+                text = f.read()
+        except OSError:
+            continue
+        flags.update(re.findall(r"--[a-z][a-z0-9-]*", text))
     return flags
 
 

@@ -6,7 +6,8 @@ metadata, so CI can archive the perf trajectory (step times and
 chunk-chooser verdicts per dispatch path / topology).  The JSON is
 re-written after *every* suite, so a crash mid-sweep never loses the rows
 already measured — the failing suite is recorded as a ``<name>_FAILED``
-row carrying the exception.  Usage:
+row carrying the exception, and the harness exits nonzero once every
+suite has run.  Usage:
 
     PYTHONPATH=src python -m benchmarks.run            # all
     PYTHONPATH=src python -m benchmarks.run --only table1 fig4
@@ -18,6 +19,7 @@ row carrying the exception.  Usage:
 import argparse
 import json
 import platform
+import sys
 import time
 
 
@@ -43,7 +45,7 @@ def _write_json(path, sel, suite_times, quick, rows, complete):
         json.dump(payload, f, indent=1)
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", nargs="*", default=None)
     ap.add_argument("--quick", action="store_true")
@@ -53,6 +55,8 @@ def main() -> None:
                          "suite so partial sweeps survive a crash")
     args = ap.parse_args()
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
     from benchmarks import (ablation_dispatch, dispatch_sweep,
                             fig3_convergence, fig4_throughput,
                             fig5_fastermoe, fig6_dispatch, fig_overlap,
@@ -96,7 +100,12 @@ def main() -> None:
 
     if args.json:
         print(f"[wrote {args.json}: {len(rows)} rows]")
+    failed = [n for n, _, _ in rows if n.endswith("_FAILED")]
+    if failed:
+        print(f"[{len(failed)} suite(s) failed: {', '.join(failed)}]")
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

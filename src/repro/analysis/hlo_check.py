@@ -236,12 +236,7 @@ def expected_inventory(sc: Scenario) -> list:
 def lower_scenario(sc: Scenario) -> str:
     import jax
     import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-
-    try:
-        from jax.sharding import AbstractMesh
-    except ImportError:  # jax 0.4.x
-        from jax._src.mesh import AbstractMesh
+    from jax.sharding import AbstractMesh, PartitionSpec as P
 
     from repro.compat import shard_map
     from repro.core import dispatch as dispatch_lib, gating
@@ -272,7 +267,7 @@ def lower_scenario(sc: Scenario) -> str:
                                    num_chunks=sc.num_chunks,
                                    use_pallas=sc.use_pallas, **kwargs)
 
-    mesh = AbstractMesh(tuple(zip(names, sc.axis_sizes)))
+    mesh = AbstractMesh(tuple(sc.axis_sizes), tuple(names))
     if sc.path == "einsum":
         # the shard-local oracle: everything replicated, no mesh traffic
         pspecs, xspec = jax.tree.map(lambda _: P(), params), P()

@@ -5,8 +5,9 @@ until now only enforced by review:
 
 * **raw-shard-map** — ``jax.shard_map`` / ``jax.make_mesh`` / the
   ``jax.experimental.shard_map`` module may only be touched by
-  ``repro/compat.py`` (the version-portability shim every other module
-  must import from — see ROADMAP "Version portability").
+  ``repro/compat.py`` (the thin wrapper every other module must import
+  from, so the Auto axis types and the ``check_vma`` default are chosen
+  once).
 * **np-in-traced** — a ``np.*`` *call* inside a jit/custom_vjp-traced
   function executes at trace time and bakes its result into the jaxpr as
   a constant: silent recompiles, no grad, wrong under vmap.  Traced code

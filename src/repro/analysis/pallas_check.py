@@ -7,7 +7,8 @@ layout, without executing anything:
 
 * **vmem-budget** — the per-grid-step working set (every in/out block,
   double-buffered unless its index map is constant over the grid i.e.
-  the block is resident, plus scratch) must fit the VMEM budget.
+  the block is resident, plus scratch) must fit the VMEM budget; operands
+  left in HBM (``hbm``) take none.
 * **index-bounds** — every block index the index maps produce over the
   *entire* grid (scalar-prefetch vectors included) must address a block
   inside the declared array shape.
@@ -59,6 +60,8 @@ def check_vmem(layout) -> list[Violation]:
     total = 0
     for b in layout.blocks:
         nbytes = math.prod(b.block_shape) * b.dtype_bytes
+        if b.hbm:
+            continue
         if b.kind == "scratch":
             total += nbytes
         else:

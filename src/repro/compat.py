@@ -1,47 +1,30 @@
-"""Version-portability shims for the JAX API surface this repo uses.
+"""The one place this repo reaches into ``jax`` for meshes and shard_map.
 
-The codebase targets the modern API (``jax.shard_map`` with ``check_vma``,
-``jax.make_mesh(..., axis_types=...)``) but must also run on jax 0.4.x,
-where ``shard_map`` lives in ``jax.experimental.shard_map`` (with the
-``check_rep`` spelling) and ``jax.sharding.AxisType`` does not exist yet.
-Everything below degrades gracefully in both directions; import from here
-instead of reaching into ``jax`` directly for these three entry points.
+Every mesh axis is marked ``AxisType.Auto`` (the repo relies on auto
+sharding propagation outside shard_map regions), and ``shard_map`` runs
+with ``check_vma=False`` by default.  Import these two entry points from
+here instead of from ``jax`` directly (``repro.analysis.lint`` enforces
+it), so the choice is made once.
 """
 
 from __future__ import annotations
 
 import jax
 
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
 
 def make_mesh(shape, axes, *, devices=None):
-    """``jax.make_mesh`` with explicit-Auto axis types when supported.
-
-    On jax >= 0.5 every axis is marked ``AxisType.Auto`` (the repo relies on
-    auto sharding propagation outside shard_map regions); on 0.4.x the
-    ``axis_types`` kwarg does not exist and Auto is the only behaviour.
-    """
+    """``jax.make_mesh`` with every axis marked ``AxisType.Auto``."""
     kwargs = {}
     if devices is not None:
         kwargs["devices"] = devices
-    if _AXIS_TYPE is not None:
-        kwargs["axis_types"] = (_AXIS_TYPE.Auto,) * len(axes)
-    return jax.make_mesh(tuple(shape), tuple(axes), **kwargs)
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         **kwargs)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` on new jax, ``jax.experimental.shard_map`` on 0.4.x.
-
-    ``check_vma`` maps onto the old API's ``check_rep``; both default to
-    False here because the MoE bodies return replicated metrics computed
-    via pmean, which the rep checker cannot always verify.
-    """
-    if hasattr(jax, "shard_map"):
-        sm = jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_vma=check_vma)
-    else:
-        from jax.experimental.shard_map import shard_map as _shard_map
-        sm = _shard_map(f, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=check_vma)
-    return sm
+    """``jax.shard_map``; ``check_vma`` defaults to False because the MoE
+    bodies return replicated metrics computed via pmean, which the
+    checker cannot always verify."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

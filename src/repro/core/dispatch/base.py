@@ -135,8 +135,9 @@ def init_moe_params(key, cfg: MoEConfig, ep: EPSpec, gate_cfg: gating.GateConfig
     """
     keys = jax.random.split(key, 8)
     d, f, n = cfg.d_model, cfg.d_ff, cfg.num_experts
-    s1 = (1.0 / np.sqrt(d))
-    s2 = (1.0 / np.sqrt(f))
+    # python floats: a numpy float64 scale would promote bf16 weights to f32
+    s1 = float(1.0 / np.sqrt(d))
+    s2 = float(1.0 / np.sqrt(f))
     p = {
         "gate": gating.init_gate_params(keys[0], d, gate_cfg),
         "w_in": jax.random.normal(keys[1], (n, d, f), cfg.dtype) * s1,

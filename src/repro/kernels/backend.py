@@ -103,7 +103,10 @@ class BlockDecl:
     or index map).  ``acc_guarded`` declares that revisits of the same
     output block across a non-trailing grid dimension are protected by a
     zero-init + read-modify-write accumulation (the fused megakernel's
-    scatter pattern); the analyzer rejects unguarded revisits.
+    scatter pattern); the analyzer rejects unguarded revisits.  ``hbm``
+    marks an operand left in HBM (``memory_space=pl.ANY``) whose rows the
+    kernel moves by DMA: its window is the whole array and it takes no
+    VMEM.
     """
 
     name: str
@@ -113,6 +116,7 @@ class BlockDecl:
     array_shape: tuple[int, ...] | None = None
     index_map: Callable[..., tuple[int, ...]] | None = None
     acc_guarded: bool = False
+    hbm: bool = False
 
 
 @dataclasses.dataclass(frozen=True)

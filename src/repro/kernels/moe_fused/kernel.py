@@ -19,21 +19,24 @@ per-slot combine weights feed the GEMM's ``block_row`` / ``block_eid`` /
     block_eid[b]      expert whose weights block ``b`` multiplies
     block_nvalid[b]   runtime valid-row count of block ``b`` (0..bc)
 
-Each grid step's *gather prologue* (first f block of a row block) pulls
-its ``bc`` input rows straight from the resident [T + 1, d] token buffer
-via ``slot_to_token`` — the sorted [S, d] buffer never exists in HBM.
+Each grid step's *gather prologue* (first f block of a row block) DMAs
+its live input rows straight from the token buffer in HBM via
+``slot_to_token`` — the sorted [S, d] buffer never exists in HBM.
 ``pl.when(block_nvalid > 0)`` gates the whole body exactly as in the
-ragged GEMM, so slack blocks still issue zero matmuls.  The *combine
-epilogue* (last f block) mirrors ``unpermute``: the f32 down-projection
-accumulator is scatter-accumulated into the resident [T, d] output with
-the gate-weight multiply fused in, walking only the block's ``nvalid``
-live slots (valid slots are a segment prefix, so none is the sentinel).
+ragged GEMM, so slack blocks still issue zero DMAs and zero matmuls.  The
+*combine epilogue* (last f block) mirrors ``unpermute``: each live slot's
+f32 down-projection row, times its gate weight, is added into its token's
+row of the [T, d] output by a read-modify-write of that row in HBM
+(valid slots are a segment prefix, so none is the sentinel, and a token
+picks an expert at most once, so the rows of one block are distinct and
+their read-modify-writes cannot race).
 
-Both residents (token input, combined output) use constant-index-map
-whole-array blocks, which bounds the fused path to layouts whose
-[T, d] + [S] vectors fit VMEM alongside the weight blocks — exactly the
-local-stage shapes the engine routes here (remote stages keep the
-permute → a2a → ragged GEMM chain; see ``engine._staged_a2a``).
+Neither the tokens nor the output is resident in VMEM, so the working set
+is the weight blocks plus three ``[block_c, d]`` f32 buffers whatever the
+token count: the kernel takes a training step's 8k tokens as it takes a
+decode step's few.  Rows travel through the ``[N, 1, d]`` float32 view of
+``moe_permute.kernel.rows_view`` (one row, one DMA), and the output starts
+as an aliased zero buffer.
 """
 
 from __future__ import annotations
@@ -48,15 +51,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import backend
 from repro.kernels.moe_gemm.kernel import _ffn_body
+from repro.kernels.moe_permute.kernel import _dma_rows, _hbm_map, rows_view
 
 
 # BlockSpec index maps, named so the analyzer layout (bottom of file)
 # evaluates the exact functions the pallas_call uses.
-
-def _resident_map(b, j, tok, w, row, eid, nv):
-    # whole-array block, resident across the entire grid
-    return (0, 0)
-
 
 def _fused_win_map(b, j, tok, w, row, eid, nv):
     return (eid[b], 0, j)
@@ -67,18 +66,14 @@ def _fused_wout_map(b, j, tok, w, row, eid, nv):
 
 
 def _fused_kernel(tok_ref, w_ref, row_ref, eid_ref, nvalid_ref,
-                  x_ref, win_ref, wgate_ref, wout_ref, o_ref,
-                  acc_ref, xblk_ref, *, activation: str, block_c: int):
+                  x_hbm, win_ref, wgate_ref, wout_ref, _zeros, o_hbm,
+                  acc_ref, xblk_ref, rows_ref, sem, *, activation: str,
+                  block_c: int):
     b = pl.program_id(0)               # row-block index (scalar-prefetched)
     j = pl.program_id(1)               # f-block index (sequential)
     nf = pl.num_programs(1)
     nv = nvalid_ref[b]                 # runtime valid rows of this block
     base = row_ref[b] * block_c        # first slot of this block
-
-    @pl.when((b == 0) & (j == 0))
-    def _zero_out():
-        # the combined output accumulates across row blocks; zero it once
-        o_ref[...] = jnp.zeros_like(o_ref)
 
     # the same occupancy predicate as the ragged GEMM: row blocks past a
     # segment's realized count do zero gathers, zero MXU work, zero stores
@@ -86,15 +81,18 @@ def _fused_kernel(tok_ref, w_ref, row_ref, eid_ref, nvalid_ref,
     def _compute():
         @pl.when(j == 0)
         def _gather():
-            # dispatch fused in: pull the block's rows straight from the
-            # token buffer (sentinel slots read the trailing zero row)
-            def body(i, _):
-                t = tok_ref[base + i]
-                xblk_ref[pl.ds(i, 1), :] = x_ref[pl.ds(t, 1), :]
-                return 0
-            jax.lax.fori_loop(0, block_c, body, 0)
+            # dispatch fused in: DMA the block's live rows straight from
+            # the token buffer; rows past nv stay stale and are masked
+            _dma_rows(nv, lambda i: x_hbm.at[tok_ref[base + i]],
+                      lambda i: rows_ref.at[i], sem)
 
-        part = _ffn_body(xblk_ref[...], win_ref, wgate_ref, wout_ref,
+            def body(i, _):
+                xblk_ref[pl.ds(i, 1), :] = rows_ref[i]
+                return 0
+            jax.lax.fori_loop(0, nv, body, 0)
+
+        x = xblk_ref[...].astype(win_ref.dtype)
+        part = _ffn_body(x, win_ref, wgate_ref, wout_ref,
                          activation=activation)
         rows = jax.lax.broadcasted_iota(jnp.int32, part.shape, 0)
         part = jnp.where(rows < nv, part, 0.0)
@@ -109,57 +107,66 @@ def _fused_kernel(tok_ref, w_ref, row_ref, eid_ref, nvalid_ref,
 
         @pl.when(j == nf - 1)
         def _scatter():
-            # combine fused in: scatter-accumulate the finished rows into
-            # token order with the gate-weight multiply applied — only the
-            # nv live slots, none of which is the sentinel
+            # combine fused in: read-modify-write each live slot's token
+            # row with the gate-weight multiply applied
+            def out_row(i):
+                return o_hbm.at[tok_ref[base + i]]
+
+            _dma_rows(nv, out_row, lambda i: rows_ref.at[i], sem)
+
             def body(i, _):
-                t = tok_ref[base + i]
-                w = w_ref[base + i]
-                o_ref[pl.ds(t, 1), :] += w * acc_ref[pl.ds(i, 1), :]
+                rows_ref[i] = (rows_ref[i]
+                               + w_ref[base + i] * acc_ref[pl.ds(i, 1), :])
                 return 0
             jax.lax.fori_loop(0, nv, body, 0)
+            _dma_rows(nv, lambda i: rows_ref.at[i], out_row, sem)
 
 
-def local_moe_pallas(x_padded, slot_to_token, slot_w, block_row, block_eid,
+def local_moe_pallas(x, slot_to_token, slot_w, block_row, block_eid,
                      block_nvalid, w_in, w_gate, w_out, *,
                      activation: str = "swiglu", block_c: int,
                      block_f: int = 256, interpret: bool = False):
-    """x_padded: [T + 1, d] tokens (last row zeros); slot_to_token: [S]
-    int32 in [0, T]; slot_w: [S] float32; block vectors as in
+    """x: [T, d] tokens; slot_to_token: [S] int32 in [0, T] (``T`` marks
+    empty slots, which only ever sit past a segment's valid count);
+    slot_w: [S] float32; block vectors as in
     ``moe_gemm.kernel.grouped_ffn_ragged_pallas``.  Returns the [T, d]
     float32 combined output (cast at the caller)."""
-    T = x_padded.shape[0] - 1
-    d = x_padded.shape[-1]
+    T, d = x.shape
     f = w_in.shape[-1]
     bc = block_c
     bf = min(block_f, f)
     nb = block_row.shape[0]
     nf = pl.cdiv(f, bf)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(nb, nf),
         in_specs=[
-            # whole token buffer resident across the grid
-            pl.BlockSpec((T + 1, d), _resident_map),
+            any_spec,                  # token rows, gathered by DMA
             pl.BlockSpec((1, d, bf), _fused_win_map),
             pl.BlockSpec((1, d, bf), _fused_win_map),
             pl.BlockSpec((1, bf, d), _fused_wout_map),
+            any_spec,                  # zero output, aliased below
         ],
-        # whole combined output resident: row blocks of the same token
-        # accumulate into it across the sequential grid
-        out_specs=pl.BlockSpec((T, d), _resident_map),
+        out_specs=any_spec,
         scratch_shapes=[pltpu.VMEM((bc, d), jnp.float32),
-                        pltpu.VMEM((bc, d), x_padded.dtype)],
+                        pltpu.VMEM((bc, d), jnp.float32),
+                        pltpu.VMEM((bc, 1, d), jnp.float32),
+                        pltpu.SemaphoreType.DMA],
     )
     kernel = functools.partial(_fused_kernel, activation=activation,
                                block_c=bc)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((T, 1, d), jnp.float32),
+        # operand 9 (after the five prefetch vectors) is the zero output
+        input_output_aliases={9: 0},
         interpret=interpret,
     )(slot_to_token, slot_w.astype(jnp.float32), block_row, block_eid,
-      block_nvalid, x_padded, w_in, w_gate, w_out)
+      block_nvalid, rows_view(x), w_in, w_gate, w_out,
+      jnp.zeros((T, 1, d), jnp.float32))
+    return out.reshape(T, d)
 
 
 # ---------------------------------------------------------------------------
@@ -169,12 +176,12 @@ def local_moe_pallas(x_padded, slot_to_token, slot_w, block_row, block_eid,
 
 @backend.register_kernel("moe_fused.local_moe")
 def _fused_layouts():
-    """Canonical fused-megakernel layout.  The [T, d] output block is
-    revisited by *every* row block (its index map is constant while the
+    """Canonical fused-megakernel layout.  The [T, d] output is revisited
+    by *every* row block (its window is the whole array while the
     non-trailing grid dimension b varies) — the exact scatter-revisit
     pattern the analyzer requires ``acc_guarded`` for; the kernel earns
-    the flag with its ``(b == 0) & (j == 0)`` zero-init plus ``+=``
-    scatter epilogue."""
+    the flag with its zero-initialized (aliased) output and per-row
+    read-modify-write epilogue."""
     from repro.kernels.moe_gemm import ops
 
     E, T, d, f = 4, 128, 128, 512
@@ -193,18 +200,19 @@ def _fused_layouts():
         grid=grid,
         prefetch=(tok, slot_w, brow, beid, nv),
         blocks=(
-            backend.BlockDecl("x_padded", "in", 4, (T + 1, d), (T + 1, d),
-                              _resident_map),
+            backend.BlockDecl("x_rows", "in", 4, (T, 1, d), (T, 1, d),
+                              _hbm_map, hbm=True),
             backend.BlockDecl("w_in", "in", 4, (1, d, bf), (E, d, f),
                               _fused_win_map),
             backend.BlockDecl("w_gate", "in", 4, (1, d, bf), (E, d, f),
                               _fused_win_map),
             backend.BlockDecl("w_out", "in", 4, (1, bf, d), (E, f, d),
                               _fused_wout_map),
-            backend.BlockDecl("o", "out", 4, (T, d), (T, d), _resident_map,
-                              acc_guarded=True),
+            backend.BlockDecl("o", "out", 4, (T, 1, d), (T, 1, d),
+                              _hbm_map, acc_guarded=True, hbm=True),
             backend.BlockDecl("acc", "scratch", 4, (bc, d)),
             backend.BlockDecl("xblk", "scratch", 4, (bc, d)),
+            backend.BlockDecl("rows", "scratch", 4, (bc, 1, d)),
         ),
         meta={"block_c": int(bc), "seg_offsets": seg_offsets,
               "seg_experts": seg_experts, "block_seg": bseg,

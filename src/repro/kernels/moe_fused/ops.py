@@ -30,7 +30,6 @@ from repro.kernels.backend import (float0 as _float0,
 from repro.kernels.moe_fused import kernel
 from repro.kernels.moe_fused.ref import local_moe_ref
 from repro.kernels.moe_gemm import ops as gemm_ops
-from repro.kernels.moe_permute.ref import _with_zero_row
 
 
 def use_fused(use_pallas=None) -> bool:
@@ -50,7 +49,7 @@ def _fused_pallas(static, x, slot_to_token, slot_w, rows_valid, w_in,
                                jnp.asarray(bseg)) - jnp.asarray(bloc),
                       0, bc).astype(jnp.int32)
     return kernel.local_moe_pallas(
-        _with_zero_row(x), slot_to_token, slot_w, jnp.asarray(brow),
+        x, slot_to_token, slot_w, jnp.asarray(brow),
         jnp.asarray(beid), nvalid, w_in, w_gate, w_out,
         activation=activation, block_c=bc, block_f=block_f,
         interpret=interpret)

@@ -33,6 +33,8 @@ def main(argv=None):
 
     import jax
     import jax.numpy as jnp
+    from repro.launch import compile_cache
+    compile_cache.enable()
     from repro import sharding
     from repro.configs.base import get_config
     from repro.launch.mesh import make_host_mesh

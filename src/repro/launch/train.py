@@ -57,6 +57,8 @@ def main(argv=None):
     import ast
 
     import jax  # noqa: E402,F401  (imported after XLA_FLAGS to pin devices)
+    from repro.launch import compile_cache
+    compile_cache.enable()
     from repro.configs.base import RunConfig, get_config
     from repro.launch.mesh import (make_host_mesh, make_production_mesh,
                                    mesh_from_topology)

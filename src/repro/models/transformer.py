@@ -171,9 +171,13 @@ def layer_plan(arch: ArchConfig):
 
     mixer = "mla" if arch.mla else "attn"
     if arch.is_moe:
+        # after the dense prefix, one group per moe_period layers: dense
+        # FFNs, then the MoE FFN last (GShard's every-other-layer at 2)
         prefix = [SubLayer(mixer, "mlp")] * arch.moe.first_dense
-        group = [SubLayer(mixer, "moe")]
-        return prefix, group, arch.num_layers - arch.moe.first_dense
+        period = arch.moe.moe_period
+        group = [SubLayer(mixer, "mlp")] * (period - 1) + [SubLayer(mixer,
+                                                                  "moe")]
+        return prefix, group, (arch.num_layers - arch.moe.first_dense) // period
     # dense / vlm / audio decoder
     cross = arch.family == "audio"
     group = [SubLayer(mixer, "mlp", cross=cross)]

@@ -181,6 +181,10 @@ class TrainResult:
     skipped_steps: int = 0
     rollbacks: int = 0
     replans: int = 0
+    # host wall seconds of each step; a step waits for the device only
+    # when it is logged, so with log_every=1 these are device step times
+    # (the first one includes the compile)
+    step_seconds: list = dataclasses.field(default_factory=list)
 
 
 def _rolling_path(ckpt_path: str, step: int) -> str:
@@ -268,18 +272,23 @@ def train(arch: ArchConfig, run: RunConfig, mesh, *, steps: int,
         opt_state = adamw.init_state(params)
 
         def make_fn(c):
-            return jax.jit(make_guarded_train_step(c, run) if guarded
-                           else make_train_step(c, run))
+            if guarded:
+                # nothing donated: a skipped step keeps the previous trees
+                return jax.jit(make_guarded_train_step(c, run))
+            # the unguarded loop drops the old trees at once, so they are
+            # donated: params and moments then exist once on the device
+            return jax.jit(make_train_step(c, run), donate_argnums=(0, 1))
         step_fn = make_fn(ctx)
         data = SyntheticLM(DataConfig(vocab_size=arch.vocab_size,
                                       seq_len=run.seq_len,
                                       global_batch=run.global_batch,
                                       seed=data_seed if data_seed is not None
                                       else run.seed), arch)
-        losses, history = [], []
+        losses, history, step_seconds = [], [], []
         rolling = []                     # [(step, path)] oldest-first
         t0 = time.time()
         for i in range(steps):
+            t_step = time.time()
             # degraded-topology fallback: probe at epoch boundaries only
             # (a plan change means a re-jit, so it must land between jits)
             if (guarded and res.replan_every and i > 0
@@ -359,6 +368,7 @@ def train(arch: ArchConfig, run: RunConfig, mesh, *, steps: int,
                 _prune_rolling(rolling, ckpt_keep)
                 if chaos is not None and chaos_lib.should_corrupt(chaos, i):
                     chaos_lib.corrupt_checkpoint(rp, chaos.seed)
+            step_seconds.append(time.time() - t_step)
         dt = time.time() - t0
         if ckpt_path:
             ckpt.save(ckpt_path, {"params": params, "opt": opt_state},
@@ -369,4 +379,5 @@ def train(arch: ArchConfig, run: RunConfig, mesh, *, steps: int,
                        params=params, opt_state=opt_state,
                        skipped_steps=counters.get("skipped_steps", 0),
                        rollbacks=counters.get("rollbacks", 0),
-                       replans=counters.get("replans", 0))
+                       replans=counters.get("replans", 0),
+                       step_seconds=step_seconds)

@@ -3,6 +3,8 @@ assigned arch runs one forward/train step on CPU — output shapes + no NaNs.
 
 The FULL configs are exercised only by the dry-run (launch/dryrun.py)."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -113,3 +115,28 @@ def test_full_configs_match_assignment():
     assert get_config("deepseek_v2_lite_16b").moe.top_k == 6
     assert get_config("deepseek_v2_236b").moe.num_experts == 160
     assert get_config("deepseek_v2_lite_16b").mla.kv_lora_rank == 512
+
+
+@pytest.mark.parametrize("arch_id", [a for a in ARCH_IDS
+                                     if get_config(a).is_moe])
+def test_moe_layers_follow_moe_period(arch_id, mesh11):
+    """Every MoE arch puts its MoE FFN on every ``moe_period``-th layer
+    after the dense prefix (gpt3_medium_moe: every other layer), and its
+    expert weights keep the model dtype."""
+    arch = get_config(arch_id)
+    m = arch.moe
+    prefix, group, n_groups = transformer.layer_plan(arch)
+    ffns = [s.ffn for s in list(prefix) + list(group) * n_groups]
+    assert len(ffns) == arch.num_layers
+    assert ffns == ["mlp"] * m.first_dense + [
+        "moe" if (i + 1) % m.moe_period == 0 else "mlp"
+        for i in range(arch.num_layers - m.first_dense)]
+    small = dataclasses.replace(arch.reduced(), dtype=arch.dtype)
+    ctx = model_lib.build_ctx(small, mesh11, seq_len=SEQ, global_batch=BATCH)
+    shapes = jax.eval_shape(lambda k: transformer.init_model(k, ctx),
+                            jax.random.PRNGKey(0))
+    experts = [leaf for path, leaf in
+               jax.tree_util.tree_flatten_with_path(shapes)[0]
+               if jax.tree_util.keystr(path).endswith("['ffn']['w_in']")
+               and leaf.ndim == 4]
+    assert experts and all(e.dtype == small.jnp_dtype for e in experts)
