@@ -61,9 +61,10 @@ NUM_REQUESTS, NEW_TOKENS, PROMPT_LENS = 8, 32, (128, 256)
 #: two first-step LM losses: one bf16 ulp (2**-8), relative.  The capacity
 #: factor is num_experts / top_k, so that every expert can take every token
 #: of every source: at random init the router sends a third of the tokens
-#: past a capacity factor of 4.0 (my chip run), and only this factor drops
-#: none whatever the routing.  It keeps the one-device slot space at 64 x
-#: 512 slots, the size the serve phase's prefill already runs.
+#: past a capacity factor of 4.0 (measured on a 2x2 v5e host), and only
+#: this factor drops none whatever the routing.  It keeps the one-device
+#: slot space at 64 x 512 slots, the size the serve phase's prefill
+#: already runs.
 FOUR_CHIP_SEQ_LEN, LOSS_RTOL = 128, 2.0 ** -8
 
 _compile_seconds = [0.0]
