@@ -8,8 +8,15 @@ schema :data:`METRIC_KEYS` — missing keys are filled with neutral defaults
 by the engine so callers (shard_map out_specs, trainers, benchmarks) never
 branch on the path.  ``frac_by_level`` is a fixed-length ``[num_stages]``
 vector (one entry per dispatch stage of the EP hierarchy, stage 0 folding
-in the self level); ``frac_near`` / ``frac_far`` are derived 2-level
-aliases kept during the near/far deprecation window.
+in the self level).
+
+Every path names its parts with ``jax.named_scope`` -- ``moe.route``
+(gate, top-k, aux loss, dispatch metrics), ``moe.dispatch`` (index
+building and the token movement to the experts), ``moe.experts`` (the
+expert FFN), ``moe.combine`` (the movement back and the weighted sum) and
+``moe.shared`` (the shared experts) -- so a profile attributes each
+compiled op to one of them through its ``op_name`` metadata.  Scopes
+change metadata only, never an op.
 
 Built-in paths:
 
@@ -48,10 +55,8 @@ from repro.kernels.moe_gemm import ops as moe_gemm_ops
 from repro.kernels.moe_permute import ops as permute_ops
 
 #: Uniform metrics schema every path resolves to.  ``frac_by_level`` is a
-#: ``[num_stages]`` vector; ``frac_near``/``frac_far`` are deprecated
-#: scalar aliases (``frac_by_level[0]`` and ``1 - frac_by_level[0]``).
-METRIC_KEYS = ("aux_loss", "frac_by_level", "frac_near", "frac_far",
-               "dropped")
+#: ``[num_stages]`` vector.
+METRIC_KEYS = ("aux_loss", "frac_by_level", "dropped")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -127,9 +132,6 @@ class DispatchEngine:
         fb = jnp.asarray(fb, jnp.float32)
         out = {"aux_loss": metrics["aux_loss"],
                "frac_by_level": fb,
-               # deprecated 2-level aliases derived from the vector
-               "frac_near": fb[0],
-               "frac_far": 1.0 - fb[0],
                "dropped": jnp.asarray(metrics.get("dropped", 0.0),
                                       jnp.float32)}
         return y, out
@@ -213,9 +215,10 @@ def _staged_a2a(params, x, eng: DispatchEngine, num_chunks: int):
     # the remote staged GEMMs; the fused local path never hits the wire
     quant = cfg.wire_codec is not None and cfg.wire_codec.quantize_compute
 
-    routed = routing.route(params, x, cfg, ep, plan, gate_cfg,
-                           with_bufs=False)
-    kept_unpadded = sum(sel.valid.sum() for _, sel in routed.sels)
+    with jax.named_scope("moe.route"):
+        routed = routing.route(params, x, cfg, ep, plan, gate_cfg,
+                               with_bufs=False)
+        kept_unpadded = sum(sel.valid.sum() for _, sel in routed.sels)
     num_chunks = max(1, int(num_chunks))
     chunked = num_chunks > 1
     topk_idx = routed.gate_out["topk_idx"]
@@ -243,32 +246,37 @@ def _staged_a2a(params, x, eng: DispatchEngine, num_chunks: int):
         # occupancy (rows_per_expert) consumed directly (no count exchange:
         # the rows never leave the device)
         E_l = params["w_in"].shape[0]
-        li = routing.build_indices(
-            tuple((stage.index, sel) for stage, sel in local_work),
-            topk_idx, T)
+        with jax.named_scope("moe.dispatch"):
+            li = routing.build_indices(
+                tuple((stage.index, sel) for stage, sel in local_work),
+                topk_idx, T)
         offs, exps = [0], []
         for _stage, sel in local_work:
             width = sel.idx.shape[-1]
             for e in range(E_l):
                 offs.append(offs[-1] + width)
                 exps.append(e)
-        out_local = expert_ffn_flat(
-            params, x, tuple(offs), cfg, ep, seg_experts=tuple(exps),
-            rows_valid=li.rows_per_expert, slot_to_token=li.slot_to_token,
-            slot_w=li.slot_w, use_pallas=eng.use_pallas)        # [T, d] f32
+        with jax.named_scope("moe.experts"):
+            out_local = expert_ffn_flat(
+                params, x, tuple(offs), cfg, ep, seg_experts=tuple(exps),
+                rows_valid=li.rows_per_expert,
+                slot_to_token=li.slot_to_token, slot_w=li.slot_w,
+                use_pallas=eng.use_pallas)                      # [T, d] f32
 
     # the shared buffer builder: chunk j's capacity slice of every remote
     # stage, flattened into one sort-order index set (sync == chunk 0)
-    indices = [routing.build_indices(
-        tuple((stage.index,
-               routing.slice_selection(sel, cap_axis, j * cpc, cpc))
-              for stage, sel, cap_axis, cpc, _ in work),
-        topk_idx, T) for j in range(num_chunks)] if work else []
+    with jax.named_scope("moe.dispatch"):
+        indices = [routing.build_indices(
+            tuple((stage.index,
+                   routing.slice_selection(sel, cap_axis, j * cpc, cpc))
+                  for stage, sel, cap_axis, cpc, _ in work),
+            topk_idx, T) for j in range(num_chunks)] if work else []
 
     # occupancy-aware compute: only pay for the count exchange when the
     # ragged Pallas entry will actually consume it
     ragged = moe_gemm_ops.use_ragged(eng.use_pallas)
 
+    @jax.named_scope("moe.dispatch")
     def dispatch(j):
         di = indices[j]
         flat = permute_ops.permute(x, di.slot_to_token,
@@ -288,6 +296,7 @@ def _staged_a2a(params, x, eng: DispatchEngine, num_chunks: int):
         xin = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
         return xin, cnts
 
+    @jax.named_scope("moe.experts")
     def compute(j, v):
         # contiguous expert spans -> the segment-offset grouped GEMM entry
         xin, cnts = v
@@ -308,6 +317,7 @@ def _staged_a2a(params, x, eng: DispatchEngine, num_chunks: int):
                             use_pallas=eng.use_pallas, quantized=quant)
         return y.reshape(E_l, R, d)
 
+    @jax.named_scope("moe.combine")
     def combine(out, j, y_exp):
         if out is None:
             out = jnp.zeros((T, d), y_exp.dtype)
@@ -333,17 +343,19 @@ def _staged_a2a(params, x, eng: DispatchEngine, num_chunks: int):
     if cfg.num_shared_experts:
         # independent of every chunk: another overlap opportunity for the
         # scheduler, issued after the pipeline drains.
-        out = out + shared_ffn(params, x, cfg, ep).astype(out.dtype)
+        with jax.named_scope("moe.shared"):
+            out = out + shared_ffn(params, x, cfg, ep).astype(out.dtype)
 
-    frac = gating.dispatch_fractions(routed.gate_out["topk_idx"],
-                                     cfg.num_experts)
-    metrics = {
-        "aux_loss": routed.aux,
-        "frac_by_level": gating.frac_by_level(frac, routed.levels,
-                                              plan.num_stages),
-        "dropped": 1.0 - jnp.minimum(
-            kept_unpadded / (T * gate_cfg.top_k), 1.0),
-    }
+    with jax.named_scope("moe.route"):
+        frac = gating.dispatch_fractions(routed.gate_out["topk_idx"],
+                                         cfg.num_experts)
+        metrics = {
+            "aux_loss": routed.aux,
+            "frac_by_level": gating.frac_by_level(frac, routed.levels,
+                                                  plan.num_stages),
+            "dropped": 1.0 - jnp.minimum(
+                kept_unpadded / (T * gate_cfg.top_k), 1.0),
+        }
     return out.astype(x.dtype), metrics
 
 
@@ -381,16 +393,19 @@ def _gather_path(params, x, eng: DispatchEngine):
     for c, s in zip(coords, ep.axis_sizes):
         my_rank = my_rank * s + c
 
-    xg = tr.gather(x)
-    levels = gating.expert_levels_nd(cfg.num_experts, E_l, ep.axis_sizes,
-                                     coords)
-    # levels=None for the gate itself: the hir bias is rank-relative and
-    # every rank gates the *gathered* tokens here, so biasing would make
-    # the implied routing rank-dependent.  The aux loss below does use the
-    # levels — gather is a first-class training path, so it reports the
-    # real balance/topology loss (decode callers ignore metrics anyway).
-    gate_out = gating.gate_forward(params["gate"], xg, gate_cfg, None)
-    aux = gating.aux_loss(gate_out, gate_cfg, levels)
+    with jax.named_scope("moe.dispatch"):
+        xg = tr.gather(x)
+    with jax.named_scope("moe.route"):
+        levels = gating.expert_levels_nd(cfg.num_experts, E_l,
+                                         ep.axis_sizes, coords)
+        # levels=None for the gate itself: the hir bias is rank-relative
+        # and every rank gates the *gathered* tokens here, so biasing would
+        # make the implied routing rank-dependent.  The aux loss below does
+        # use the levels — gather is a first-class training path, so it
+        # reports the real balance/topology loss (decode callers ignore
+        # metrics anyway).
+        gate_out = gating.gate_forward(params["gate"], xg, gate_cfg, None)
+        aux = gating.aux_loss(gate_out, gate_cfg, levels)
 
     Tg, d = xg.shape
     if moe_fused_ops.use_fused(eng.use_pallas):
@@ -402,34 +417,43 @@ def _gather_path(params, x, eng: DispatchEngine):
         # An expert picked by *no* gathered token is pure slack: its whole
         # Tg-row segment is a skipped zero-valid segment, exactly the
         # whole-segment skip the ragged GEMM did here before.
-        wts = routing.gather_weights(gate_out, my_rank, E_l)     # [Tg, E_l]
-        valid = jnp.where(jnp.any(wts > 0, axis=0), Tg, 0).astype(jnp.int32)
+        with jax.named_scope("moe.route"):
+            wts = routing.gather_weights(gate_out, my_rank, E_l)  # [Tg, E_l]
+            valid = jnp.where(jnp.any(wts > 0, axis=0), Tg,
+                              0).astype(jnp.int32)
         slot_tok = jnp.tile(jnp.arange(Tg, dtype=jnp.int32), E_l)
-        y = expert_ffn_flat(params, xg, transport.expert_segments(E_l, Tg),
-                            cfg, ep, seg_experts=tuple(range(E_l)),
-                            rows_valid=valid, slot_to_token=slot_tok,
-                            slot_w=wts.T.reshape(-1),
-                            use_pallas=eng.use_pallas)           # [Tg, d]
+        with jax.named_scope("moe.experts"):
+            y = expert_ffn_flat(params, xg,
+                                transport.expert_segments(E_l, Tg),
+                                cfg, ep, seg_experts=tuple(range(E_l)),
+                                rows_valid=valid, slot_to_token=slot_tok,
+                                slot_w=wts.T.reshape(-1),
+                                use_pallas=eng.use_pallas)       # [Tg, d]
     else:
-        xin = jnp.broadcast_to(xg, (E_l,) + xg.shape)            # [E_l, Tg, d]
-        y = expert_ffn(params, xin, cfg, ep)                     # [E_l, Tg, d]
+        with jax.named_scope("moe.experts"):
+            xin = jnp.broadcast_to(xg, (E_l,) + xg.shape)        # [E_l, Tg, d]
+            y = expert_ffn(params, xin, cfg, ep)                 # [E_l, Tg, d]
         # combine through the same weighted inverse-permutation the staged
         # paths use: the dense [E_l, Tg] grid is a degenerate slot buffer
-        inv_idx, inv_w = routing.gather_inverse(gate_out, my_rank, E_l, Tg)
-        y = permute_ops.unpermute(y.reshape(E_l * Tg, -1), inv_idx, inv_w,
-                                  use_pallas=eng.use_pallas)     # [Tg, d]
-    y = y.astype(x.dtype)
-
-    y = tr.reduce(y)
-    y = tr.slice_local(y, my_rank, x.shape[0])
+        with jax.named_scope("moe.combine"):
+            inv_idx, inv_w = routing.gather_inverse(gate_out, my_rank, E_l,
+                                                    Tg)
+            y = permute_ops.unpermute(y.reshape(E_l * Tg, -1), inv_idx,
+                                      inv_w, use_pallas=eng.use_pallas)
+    with jax.named_scope("moe.combine"):
+        y = y.astype(x.dtype)
+        y = tr.reduce(y)
+        y = tr.slice_local(y, my_rank, x.shape[0])
 
     if cfg.num_shared_experts:
-        y = y + shared_ffn(params, x, cfg, ep).astype(y.dtype)
+        with jax.named_scope("moe.shared"):
+            y = y + shared_ffn(params, x, cfg, ep).astype(y.dtype)
 
-    frac = gating.dispatch_fractions(gate_out["topk_idx"], cfg.num_experts)
-    metrics = {"aux_loss": aux,
-               "frac_by_level": gating.frac_by_level(frac, levels,
-                                                     eng.num_stages),
+    with jax.named_scope("moe.route"):
+        frac = gating.dispatch_fractions(gate_out["topk_idx"],
+                                         cfg.num_experts)
+        fb = gating.frac_by_level(frac, levels, eng.num_stages)
+    metrics = {"aux_loss": aux, "frac_by_level": fb,
                "dropped": 0.0}   # no capacity limit: nothing ever drops
     return y.astype(x.dtype), metrics
 
@@ -457,32 +481,38 @@ def _einsum_path(params, x, eng: DispatchEngine):
     if capacity is None:
         capacity = max(1, int(T * K * cfg.capacity_factor / N))
 
-    gate_out = gating.gate_forward(params["gate"], x, gate_cfg, None)
-    aux = gating.aux_loss(gate_out, gate_cfg, None)
+    with jax.named_scope("moe.route"):
+        gate_out = gating.gate_forward(params["gate"], x, gate_cfg, None)
+        aux = gating.aux_loss(gate_out, gate_cfg, None)
     topk_idx, topk_w = gate_out["topk_idx"], gate_out["topk_weight"]
 
-    # position of each (token, slot) within its expert's capacity buffer
-    dispatch = jnp.zeros((T, N, capacity), jnp.float32)
-    combine = jnp.zeros((T, N, capacity), jnp.float32)
-    counts = jnp.zeros((N,), jnp.int32)
-    for s in range(K):
-        e = topk_idx[:, s]                       # [T]
-        onehot = jax.nn.one_hot(e, N, dtype=jnp.int32)        # [T, N]
-        pos_in_e = (jnp.cumsum(onehot, axis=0) - 1) * onehot   # [T, N]
-        pos = jnp.sum(pos_in_e, axis=1) + counts[e]            # [T]
-        keep = pos < capacity
-        slot = jax.nn.one_hot(pos, capacity, dtype=jnp.float32)
-        mask = (onehot.astype(jnp.float32) * keep[:, None].astype(jnp.float32))
-        d_s = mask[:, :, None] * slot[:, None, :]              # [T, N, C]
-        dispatch = dispatch + d_s
-        combine = combine + d_s * topk_w[:, s][:, None, None]
-        counts = counts + jnp.sum(onehot * keep[:, None], axis=0)
+    with jax.named_scope("moe.dispatch"):
+        # position of each (token, slot) within its expert's capacity buffer
+        dispatch = jnp.zeros((T, N, capacity), jnp.float32)
+        combine = jnp.zeros((T, N, capacity), jnp.float32)
+        counts = jnp.zeros((N,), jnp.int32)
+        for s in range(K):
+            e = topk_idx[:, s]                                 # [T]
+            onehot = jax.nn.one_hot(e, N, dtype=jnp.int32)     # [T, N]
+            pos_in_e = (jnp.cumsum(onehot, axis=0) - 1) * onehot   # [T, N]
+            pos = jnp.sum(pos_in_e, axis=1) + counts[e]        # [T]
+            keep = pos < capacity
+            slot = jax.nn.one_hot(pos, capacity, dtype=jnp.float32)
+            mask = (onehot.astype(jnp.float32)
+                    * keep[:, None].astype(jnp.float32))
+            d_s = mask[:, :, None] * slot[:, None, :]          # [T, N, C]
+            dispatch = dispatch + d_s
+            combine = combine + d_s * topk_w[:, s][:, None, None]
+            counts = counts + jnp.sum(onehot * keep[:, None], axis=0)
+        xin = jnp.einsum("tnc,td->ncd", dispatch, x.astype(jnp.float32))
 
-    xin = jnp.einsum("tnc,td->ncd", dispatch, x.astype(jnp.float32))
-    y_exp = expert_ffn(params, xin.astype(x.dtype), cfg, ep)   # [N, C, d]
-    y = jnp.einsum("tnc,ncd->td", combine, y_exp.astype(jnp.float32))
+    with jax.named_scope("moe.experts"):
+        y_exp = expert_ffn(params, xin.astype(x.dtype), cfg, ep)  # [N, C, d]
+    with jax.named_scope("moe.combine"):
+        y = jnp.einsum("tnc,ncd->td", combine, y_exp.astype(jnp.float32))
     if cfg.num_shared_experts:
-        y = y + shared_ffn(params, x, cfg, ep).astype(y.dtype)
+        with jax.named_scope("moe.shared"):
+            y = y + shared_ffn(params, x, cfg, ep).astype(y.dtype)
     metrics = {"aux_loss": aux,
                "dropped": 1.0 - dispatch.sum() / (T * K)}
     return y.astype(x.dtype), metrics

@@ -11,9 +11,8 @@ New code should import from ``repro.core.dispatch`` directly (or go through
 ``models/transformer._moe_block``, which already does); each ``moe_apply_*``
 wrapper emits a ``DeprecationWarning`` on use.  Note one schema change the
 wrappers inherit: every path now returns the uniform metrics dict
-``("aux_loss", "frac_by_level", "frac_near", "frac_far", "dropped")`` —
-``frac_by_level`` is the level-indexed vector, ``frac_near``/``frac_far``
-its deprecated 2-level aliases.
+``("aux_loss", "frac_by_level", "dropped")`` — ``frac_by_level`` is the
+level-indexed vector.
 """
 
 from __future__ import annotations

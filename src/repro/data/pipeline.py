@@ -14,6 +14,7 @@ is offline).  The stream is a reproducible mixture of Zipf-distributed
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -47,6 +48,7 @@ class SyntheticLM:
         p = 1.0 / ranks ** cfg.zipf_a
         self._probs = p / p.sum()
 
+    @functools.partial(jax.profiler.annotate_function, name="data.batch")
     def batch(self, step: int) -> dict:
         """Batch for a given step (stateless — random access by step)."""
         cfg = self.cfg
@@ -81,6 +83,7 @@ class SyntheticLM:
             step += 1
 
 
+@functools.partial(jax.profiler.annotate_function, name="data.shard")
 def shard_batch(batch: dict, mesh, batch_axes=("pod", "data")):
     """Place a host batch on the mesh, sharded over the batch axes."""
     from jax.sharding import NamedSharding, PartitionSpec as P

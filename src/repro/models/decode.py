@@ -150,6 +150,7 @@ def _select_batch(mask, new, old):
     return out
 
 
+@jax.named_scope("layer")
 def _decode_sublayer(p, c, x, sub: SubLayer, ctx: ModelCtx, layer_idx=None):
     a = ctx.arch
     h = layers.norm_apply(p["norm1"], x, a.norm)
@@ -203,32 +204,38 @@ def decode_step(params, cache, tokens, ctx: ModelCtx):
             layer_idx=i)
 
     n_prefix = len(prefix)
-    if _overrides_hit_groups(ctx, n_prefix, group, n_groups, decode=True):
-        # layer-dependent dispatch inside the groups: unroll (mirrors
-        # transformer.forward_features) and restack the per-group caches.
-        new_gs = []
-        for g in range(n_groups):
-            pg = jax.tree_util.tree_map(lambda a, g=g: a[g], params["groups"])
-            cg = jax.tree_util.tree_map(lambda a, g=g: a[g], cache["groups"])
-            for j, sub in enumerate(group):
-                x, cg[f"sub{j}"] = _decode_sublayer(
-                    pg[f"sub{j}"], dict(cg[f"sub{j}"]), x, sub, ctx,
-                    layer_idx=n_prefix + g * len(group) + j)
-            new_gs.append(cg)
-        new_cache["groups"] = jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs), *new_gs)
-    else:
-        def body(x, pc):
-            p, c = pc
-            c = jax.tree_util.tree_map(lambda v: v, c)  # shallow copy
-            for j, sub in enumerate(group):
-                x, c[f"sub{j}"] = _decode_sublayer(
-                    p[f"sub{j}"], dict(c[f"sub{j}"]), x, sub, ctx)
-            return x, c
+    # the layer loop slices each layer's parameters (and cache) out of their
+    # stacks; that work, outside every sublayer's ``layer`` scope, is
+    # ``layer.params``
+    with jax.named_scope("layer.params"):
+        if _overrides_hit_groups(ctx, n_prefix, group, n_groups, decode=True):
+            # layer-dependent dispatch inside the groups: unroll (mirrors
+            # transformer.forward_features) and restack the per-group caches.
+            new_gs = []
+            for g in range(n_groups):
+                pg = jax.tree_util.tree_map(lambda a, g=g: a[g],
+                                            params["groups"])
+                cg = jax.tree_util.tree_map(lambda a, g=g: a[g],
+                                            cache["groups"])
+                for j, sub in enumerate(group):
+                    x, cg[f"sub{j}"] = _decode_sublayer(
+                        pg[f"sub{j}"], dict(cg[f"sub{j}"]), x, sub, ctx,
+                        layer_idx=n_prefix + g * len(group) + j)
+                new_gs.append(cg)
+            new_cache["groups"] = jax.tree_util.tree_map(
+                lambda *xs: jnp.stack(xs), *new_gs)
+        else:
+            def body(x, pc):
+                p, c = pc
+                c = jax.tree_util.tree_map(lambda v: v, c)  # shallow copy
+                for j, sub in enumerate(group):
+                    x, c[f"sub{j}"] = _decode_sublayer(
+                        p[f"sub{j}"], dict(c[f"sub{j}"]), x, sub, ctx)
+                return x, c
 
-        x, new_groups = jax.lax.scan(body, x, (params["groups"],
-                                               cache["groups"]))
-        new_cache["groups"] = new_groups
+            x, new_groups = jax.lax.scan(body, x, (params["groups"],
+                                                   cache["groups"]))
+            new_cache["groups"] = new_groups
     x = layers.norm_apply(params["final_norm"], x, a.norm)
     logits = layers.unembed_apply(params["embed"], x)
     return logits, new_cache
@@ -249,6 +256,7 @@ def _needs_scan_prefill(arch) -> bool:
                for sub in list(prefix) + list(group))
 
 
+@jax.named_scope("layer")
 def _prefill_sublayer(p, c, x, sub: SubLayer, ctx: ModelCtx, lens,
                       layer_idx=None):
     """Full-sequence sublayer forward that also writes the decode cache:
@@ -368,30 +376,36 @@ def prefill(params, batch, ctx: ModelCtx, *, cache_len: int, lens=None):
             lens, layer_idx=i)
 
     n_prefix = len(prefix)
-    if _overrides_hit_groups(ctx, n_prefix, group, n_groups, decode=True):
-        new_gs = []
-        for g in range(n_groups):
-            pg = jax.tree_util.tree_map(lambda a, g=g: a[g], params["groups"])
-            cg = jax.tree_util.tree_map(lambda a, g=g: a[g], cache["groups"])
-            for j, sub in enumerate(group):
-                x, cg[f"sub{j}"] = _prefill_sublayer(
-                    pg[f"sub{j}"], dict(cg[f"sub{j}"]), x, sub, ctx, lens,
-                    layer_idx=n_prefix + g * len(group) + j)
-            new_gs.append(cg)
-        new_cache["groups"] = jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs), *new_gs)
-    else:
-        def body(x, pc):
-            p, c = pc
-            c = jax.tree_util.tree_map(lambda v: v, c)  # shallow copy
-            for j, sub in enumerate(group):
-                x, c[f"sub{j}"] = _prefill_sublayer(
-                    p[f"sub{j}"], dict(c[f"sub{j}"]), x, sub, ctx, lens)
-            return x, c
+    # the layer loop slices each layer's parameters (and cache) out of their
+    # stacks; that work, outside every sublayer's ``layer`` scope, is
+    # ``layer.params``
+    with jax.named_scope("layer.params"):
+        if _overrides_hit_groups(ctx, n_prefix, group, n_groups, decode=True):
+            new_gs = []
+            for g in range(n_groups):
+                pg = jax.tree_util.tree_map(lambda a, g=g: a[g],
+                                            params["groups"])
+                cg = jax.tree_util.tree_map(lambda a, g=g: a[g],
+                                            cache["groups"])
+                for j, sub in enumerate(group):
+                    x, cg[f"sub{j}"] = _prefill_sublayer(
+                        pg[f"sub{j}"], dict(cg[f"sub{j}"]), x, sub, ctx, lens,
+                        layer_idx=n_prefix + g * len(group) + j)
+                new_gs.append(cg)
+            new_cache["groups"] = jax.tree_util.tree_map(
+                lambda *xs: jnp.stack(xs), *new_gs)
+        else:
+            def body(x, pc):
+                p, c = pc
+                c = jax.tree_util.tree_map(lambda v: v, c)  # shallow copy
+                for j, sub in enumerate(group):
+                    x, c[f"sub{j}"] = _prefill_sublayer(
+                        p[f"sub{j}"], dict(c[f"sub{j}"]), x, sub, ctx, lens)
+                return x, c
 
-        x, new_groups = jax.lax.scan(body, x, (params["groups"],
-                                               cache["groups"]))
-        new_cache["groups"] = new_groups
+            x, new_groups = jax.lax.scan(body, x, (params["groups"],
+                                                   cache["groups"]))
+            new_cache["groups"] = new_groups
 
     x = layers.norm_apply(params["final_norm"], x, a.norm)
     logits = layers.unembed_apply(params["embed"], x)

@@ -194,6 +194,7 @@ def _blockwise_sdpa(q, k, v, *, causal, sliding_window, block_k: int = 1024):
     return o.astype(q.dtype)
 
 
+@jax.named_scope("attn")
 def attn_apply(params, x, cfg: AttnConfig, positions=None):
     """Full-sequence (train / prefill) attention. x: [B, S, d]."""
     B, S, _ = x.shape
@@ -216,6 +217,7 @@ def attn_apply(params, x, cfg: AttnConfig, positions=None):
     return out.reshape(B, S, -1) @ params["wo"], (k, v)
 
 
+@jax.named_scope("attn")
 def attn_decode(params, x, cache, cfg: AttnConfig):
     """Single-token decode vs a KV cache.
 

@@ -68,6 +68,7 @@ def _q_proj(params, x, cfg: MLAConfig):
     return q  # [B, S, H, qk_dim]
 
 
+@jax.named_scope("attn")
 def mla_apply(params, x, cfg: MLAConfig, positions=None):
     """Expanded-form MLA for train/prefill.  Returns (out, cache_entry)."""
     B, S, _ = x.shape
@@ -111,6 +112,7 @@ def init_mla_cache(batch: int, max_len: int, cfg: MLAConfig):
             "pos": jnp.zeros((batch,), jnp.int32)}
 
 
+@jax.named_scope("attn")
 def mla_decode(params, x, cache, cfg: MLAConfig):
     """Absorbed-form single-token decode against the compressed cache.
 

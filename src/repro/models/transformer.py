@@ -343,6 +343,7 @@ def _merge_specs(params, partial_specs):
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("layer")
 def _apply_sublayer(p, x, sub: SubLayer, ctx: ModelCtx, *, enc_out=None,
                     aux0=0.0, frac0=None, drop0=None, layer_idx=None):
     """Returns (x, aux, frac, drop): the residual stream, the accumulated
@@ -466,37 +467,42 @@ def forward_features(params, batch, ctx: ModelCtx):
             frac0=frac, drop0=drop, layer_idx=i)
 
     n_prefix = len(prefix)
-    if _overrides_hit_groups(ctx, n_prefix, group, n_groups):
-        # a per-layer dispatch override lands inside the scanned groups:
-        # the schedule is layer-dependent, so unroll the group loop (each
-        # group gets its own HLO with its own dispatch path).
-        def run_group(carry, pg, base_idx):
-            x, aux, frac, drop = carry
-            for j, sub in enumerate(group):
-                x, aux, frac, drop = _apply_sublayer(
-                    pg[f"sub{j}"], x, sub, ctx, enc_out=enc_out, aux0=aux,
-                    frac0=frac, drop0=drop, layer_idx=base_idx + j)
-            return x, aux, frac, drop
-        if ctx.remat:
-            run_group = jax.checkpoint(run_group, static_argnums=(2,),
-                                       prevent_cse=False)
-        for g in range(n_groups):
-            pg = jax.tree_util.tree_map(lambda a, g=g: a[g], params["groups"])
-            x, aux, frac, drop = run_group((x, aux, frac, drop), pg,
-                                           n_prefix + g * len(group))
-    else:
-        def body(carry, p):
-            x, aux, frac, drop = carry
-            for j, sub in enumerate(group):
-                x, aux, frac, drop = _apply_sublayer(
-                    p[f"sub{j}"], x, sub, ctx, enc_out=enc_out, aux0=aux,
-                    frac0=frac, drop0=drop)
-            return (x, aux, frac, drop), None
+    # the layer loop slices each layer's parameters out of their stacks;
+    # that work, outside every sublayer's ``layer`` scope, is
+    # ``layer.params``
+    with jax.named_scope("layer.params"):
+        if _overrides_hit_groups(ctx, n_prefix, group, n_groups):
+            # a per-layer dispatch override lands inside the scanned groups:
+            # the schedule is layer-dependent, so unroll the group loop (each
+            # group gets its own HLO with its own dispatch path).
+            def run_group(carry, pg, base_idx):
+                x, aux, frac, drop = carry
+                for j, sub in enumerate(group):
+                    x, aux, frac, drop = _apply_sublayer(
+                        pg[f"sub{j}"], x, sub, ctx, enc_out=enc_out, aux0=aux,
+                        frac0=frac, drop0=drop, layer_idx=base_idx + j)
+                return x, aux, frac, drop
+            if ctx.remat:
+                run_group = jax.checkpoint(run_group, static_argnums=(2,),
+                                           prevent_cse=False)
+            for g in range(n_groups):
+                pg = jax.tree_util.tree_map(lambda a, g=g: a[g],
+                                            params["groups"])
+                x, aux, frac, drop = run_group((x, aux, frac, drop), pg,
+                                               n_prefix + g * len(group))
+        else:
+            def body(carry, p):
+                x, aux, frac, drop = carry
+                for j, sub in enumerate(group):
+                    x, aux, frac, drop = _apply_sublayer(
+                        p[f"sub{j}"], x, sub, ctx, enc_out=enc_out, aux0=aux,
+                        frac0=frac, drop0=drop)
+                return (x, aux, frac, drop), None
 
-        if ctx.remat:
-            body = jax.checkpoint(body, prevent_cse=False)
-        (x, aux, frac, drop), _ = jax.lax.scan(body, (x, aux, frac, drop),
-                                               params["groups"])
+            if ctx.remat:
+                body = jax.checkpoint(body, prevent_cse=False)
+            (x, aux, frac, drop), _ = jax.lax.scan(body, (x, aux, frac, drop),
+                                                   params["groups"])
 
     x = layers.norm_apply(params["final_norm"], x, a.norm)
     if frac is not None:
@@ -551,8 +557,7 @@ def loss_fn(params, batch, ctx: ModelCtx, aux_weight: float = 1.0):
     total = nll + aux_weight * aux
     metrics = {"nll": nll, "aux": aux, "loss": total}
     if frac is not None:
-        # mean per-level dispatch fractions over the MoE layers — the
-        # level-indexed replacement for the old frac_near/frac_far pair
+        # mean per-level dispatch fractions over the MoE layers
         metrics["frac_by_level"] = frac
     if drop is not None:
         # mean dropped-assignment fraction over the MoE layers (the

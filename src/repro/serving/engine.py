@@ -20,6 +20,7 @@ Three layers, lowest first:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 
 import jax
@@ -31,6 +32,10 @@ from repro.models import decode as decode_lib, model as model_lib
 from repro.models import transformer
 from repro.serving import batching
 from repro.serving.scheduler import Request, Scheduler
+
+#: A host span in the profiler's trace; costs about a microsecond when no
+#: profiler runs.
+_span = jax.profiler.TraceAnnotation
 
 
 def _with_overrides(ctx: transformer.ModelCtx, dispatch_override):
@@ -252,41 +257,56 @@ class ServingEngine:
 
     def _admit(self, sched, kv, cur, temps, key, now):
         cfg = self.cfg
-        admits = sched.take(cfg.prefill_pack, now=now)
-        if not admits:
-            return cur, key, 0
-        for _, req in admits:
-            need = req.prompt_len + req.max_new_tokens
-            if need > cfg.cache_len:
-                raise ValueError(
-                    f"request {req.uid}: prompt+new tokens {need} exceed "
-                    f"cache_len {cfg.cache_len}")
-        tokens, lens = batching.pad_pack([req.tokens for _, req in admits],
-                                         cfg.prefill_pack,
-                                         cfg.prompt_buckets)
-        batch = {"tokens": tokens, "lens": lens}
-        if any(req.frontend is not None for _, req in admits):
-            batch["frontend"] = batching.pad_frontend_pack(
-                [req.frontend for _, req in admits], cfg.prefill_pack)
-        logits, pack_cache = self._prefill(self.params, batch)
-        slots = np.full((cfg.prefill_pack,), cfg.num_slots, np.int32)
-        slots[:len(admits)] = [s for s, _ in admits]
-        slots = jnp.asarray(slots)
-        kv.insert(pack_cache, slots)
-        pack_temps = np.zeros((cfg.prefill_pack,), np.float32)
-        for i, (s, req) in enumerate(admits):
-            temps[s] = req.temperature
-            pack_temps[i] = req.temperature
-        key, sub = jax.random.split(key)
-        first = self._sample(logits, jnp.asarray(pack_temps), sub)
-        cur = self._scatter(cur, slots, first)
-        for i, (s, _) in enumerate(admits):
-            if sched.on_token(s, int(first[i])):
-                sched.complete(s, now=time.time())
-        return cur, key, 1
+        with _span("serve.admit") as span:
+            admits = sched.take(cfg.prefill_pack, now=now)
+            if not admits:
+                return cur, key, 0
+            if _span.is_enabled():      # only while a profiler records
+                span.set_metadata(
+                    uids=",".join(str(r.uid) for _, r in admits),
+                    slots=",".join(str(s) for s, _ in admits))
+            for _, req in admits:
+                need = req.prompt_len + req.max_new_tokens
+                if need > cfg.cache_len:
+                    raise ValueError(
+                        f"request {req.uid}: prompt+new tokens {need} exceed "
+                        f"cache_len {cfg.cache_len}")
+            tokens, lens = batching.pad_pack([req.tokens for _, req in admits],
+                                             cfg.prefill_pack,
+                                             cfg.prompt_buckets)
+            batch = {"tokens": tokens, "lens": lens}
+            if any(req.frontend is not None for _, req in admits):
+                batch["frontend"] = batching.pad_frontend_pack(
+                    [req.frontend for _, req in admits], cfg.prefill_pack)
+            logits, pack_cache = self._prefill(self.params, batch)
+            slots = np.full((cfg.prefill_pack,), cfg.num_slots, np.int32)
+            slots[:len(admits)] = [s for s, _ in admits]
+            slots = jnp.asarray(slots)
+            kv.insert(pack_cache, slots)
+            pack_temps = np.zeros((cfg.prefill_pack,), np.float32)
+            for i, (s, req) in enumerate(admits):
+                temps[s] = req.temperature
+                pack_temps[i] = req.temperature
+            key, sub = jax.random.split(key)
+            first = self._sample(logits, jnp.asarray(pack_temps), sub)
+            cur = self._scatter(cur, slots, first)
+            with _span("serve.admit.wait"):
+                first = np.asarray(first)
+            t_first = time.time()
+            for i, (s, _) in enumerate(admits):
+                sched.stream(s).t_first_token = t_first
+                if sched.on_token(s, int(first[i])):
+                    sched.complete(s, now=time.time())
+            return cur, key, 1
 
+    @functools.partial(jax.profiler.annotate_function, name="serve.run")
     def run(self, requests, *, seed: int = 0) -> ServingReport:
-        """Serve ``requests`` to completion; returns per-stream stats."""
+        """Serve ``requests`` to completion; returns per-stream stats.
+
+        The loop writes profiler spans: ``serve.run`` around the call, and
+        per iteration ``serve.step`` around ``serve.admit``,
+        ``serve.decode`` and ``serve.tokens``, with ``*.wait`` where the
+        host blocks on a device result (see docs/serving.md)."""
         cfg = self.cfg
         sched = Scheduler(cfg.num_slots)
         for req in requests:
@@ -298,31 +318,36 @@ class ServingEngine:
         decode_steps = prefill_calls = evictions = 0
         t0 = time.time()
         while sched.has_work:
-            cur, key, n_pre = self._admit(sched, kv, cur, temps, key,
-                                          now=time.time())
-            prefill_calls += n_pre
-            # deadline sweep: evict overdue streams mid-decode — their KV
-            # rows are zeroed and the freed slots return to the pool for
-            # the next admission round (one stuck stream can't wedge the
-            # engine)
-            now = time.time()
-            overdue = sched.expired(now)
-            if overdue:
-                kv.evict(overdue)
-                for slot in overdue:
-                    sched.evict(slot, now=now)
-                evictions += len(overdue)
-            if not sched.num_active:
-                continue        # everything admitted finished at 1 token
-            logits, kv.cache = self._decode(self.params, kv.cache, cur)
-            key, sub = jax.random.split(key)
-            nxt = self._sample(logits[:, 0], jnp.asarray(temps), sub)
-            cur = nxt[:, None]
-            decode_steps += 1
-            nxt_host = np.asarray(nxt)
-            for slot in sched.active_slots():
-                if sched.on_token(slot, int(nxt_host[slot])):
-                    sched.complete(slot, now=time.time())
+            with _span("serve.step"):
+                cur, key, n_pre = self._admit(sched, kv, cur, temps, key,
+                                              now=time.time())
+                prefill_calls += n_pre
+                # deadline sweep: evict overdue streams mid-decode — their
+                # KV rows are zeroed and the freed slots return to the pool
+                # for the next admission round (one stuck stream can't
+                # wedge the engine)
+                now = time.time()
+                overdue = sched.expired(now)
+                if overdue:
+                    kv.evict(overdue)
+                    for slot in overdue:
+                        sched.evict(slot, now=now)
+                    evictions += len(overdue)
+                if not sched.num_active:
+                    continue    # everything admitted finished at 1 token
+                with _span("serve.decode"):
+                    logits, kv.cache = self._decode(self.params, kv.cache,
+                                                    cur)
+                    key, sub = jax.random.split(key)
+                    nxt = self._sample(logits[:, 0], jnp.asarray(temps), sub)
+                    cur = nxt[:, None]
+                decode_steps += 1
+                with _span("serve.decode.wait"):
+                    nxt_host = np.asarray(nxt)
+                with _span("serve.tokens"):
+                    for slot in sched.active_slots():
+                        if sched.on_token(slot, int(nxt_host[slot])):
+                            sched.complete(slot, now=time.time())
         wall = time.time() - t0
         total = sum(len(s.generated) for s in sched.finished)
         return ServingReport(streams=sched.finished, wall_time=wall,

@@ -48,6 +48,7 @@ class Stream:
     slot: int
     generated: list = dataclasses.field(default_factory=list)
     t_admitted: float = 0.0
+    t_first_token: float = 0.0       # the first token reached the host
     t_finished: float = 0.0
     evicted: bool = False            # deadline eviction (partial output)
 

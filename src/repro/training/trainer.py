@@ -27,6 +27,10 @@ from repro.data.pipeline import DataConfig, SyntheticLM, shard_batch
 from repro.models import model as model_lib, transformer
 from repro.optim import adamw
 
+#: A host span in the profiler's trace (``train.step``, ``train.log``,
+#: ``train.ckpt``); about a microsecond when no profiler runs.
+_span = jax.profiler.TraceAnnotation
+
 
 def make_train_step(ctx: transformer.ModelCtx, run: RunConfig,
                     opt_cfg: adamw.AdamWConfig | None = None):
@@ -232,6 +236,12 @@ def train(arch: ArchConfig, run: RunConfig, mesh, *, steps: int,
     guarded step: in-jit skip on non-finite grads, rollback on sustained
     loss spike, and the degraded-topology replan at ``replan_every``
     boundaries (plans are static per compilation, so a replan re-jits).
+
+    Under a profiler the loop writes the spans ``train.step`` (the step's
+    dispatch), ``train.log`` (the host's wait for a logged step's
+    metrics) and ``train.ckpt`` (a checkpoint save), beside the data
+    pipeline's ``data.batch`` and ``data.shard``: device idle time under
+    one of them is that part of the loop holding the chip back.
     """
     aux_mode = aux_mode or run.aux_mode
     want = run.mesh_axis_sizes()
@@ -308,8 +318,9 @@ def train(arch: ArchConfig, run: RunConfig, mesh, *, steps: int,
                 scales = chaos_lib.fault_scales(chaos, i)
                 fault = {k: jnp.float32(scales[k])
                          for k in ("loss_mult", "grad_mult")}
-                new_p, new_o, metrics = step_fn(params, opt_state, batch,
-                                                fault)
+                with _span("train.step"):
+                    new_p, new_o, metrics = step_fn(params, opt_state,
+                                                    batch, fault)
                 if scales["param_scale"] != 1.0:
                     # loss-spike fault: wreck the updated params between
                     # steps (host-gated, so the healthy path never traces
@@ -339,14 +350,17 @@ def train(arch: ArchConfig, run: RunConfig, mesh, *, steps: int,
                 else:
                     params, opt_state = new_p, new_o
             else:
-                params, opt_state, metrics = step_fn(params, opt_state,
-                                                     batch)
+                with _span("train.step"):
+                    params, opt_state, metrics = step_fn(params, opt_state,
+                                                         batch)
             if i % log_every == 0 or i == steps - 1:
                 # scalar metrics become floats; vector metrics (e.g. the
-                # level-indexed frac_by_level) become lists
-                m = {k: (float(v) if getattr(v, "ndim", 0) == 0
-                         else [float(x) for x in v])
-                     for k, v in metrics.items()}
+                # level-indexed frac_by_level) become lists: the host waits
+                # for the step here
+                with _span("train.log"):
+                    m = {k: (float(v) if getattr(v, "ndim", 0) == 0
+                             else [float(x) for x in v])
+                         for k, v in metrics.items()}
                 m.update(policy.counters() if policy is not None else
                          {"skipped_steps": 0, "rollbacks": 0, "replans": 0,
                           "drop_alarms": 0})
@@ -363,7 +377,9 @@ def train(arch: ArchConfig, run: RunConfig, mesh, *, steps: int,
             if (ckpt_path and ckpt_every > 0 and (i + 1) % ckpt_every == 0
                     and (policy is None or policy.healthy)):
                 rp = _rolling_path(ckpt_path, i)
-                ckpt.save(rp, {"params": params, "opt": opt_state}, step=i)
+                with _span("train.ckpt"):
+                    ckpt.save(rp, {"params": params, "opt": opt_state},
+                              step=i)
                 rolling.append((i, rp))
                 _prune_rolling(rolling, ckpt_keep)
                 if chaos is not None and chaos_lib.should_corrupt(chaos, i):

@@ -241,6 +241,21 @@ def test_serving_slot_reuse_never_recompiles(mesh11, key):
         assert len(report.tokens_for(r.uid)) == r.max_new_tokens
 
 
+def test_streams_stamp_their_first_token(mesh11, key):
+    """``t_first_token`` is stamped when the prefill's token reaches the
+    host: after admission, no later than completion."""
+    arch, ctx, params = _build(mesh11, key)
+    cfg = engine.ServeConfig(num_slots=2, cache_len=24, prefill_pack=2,
+                             prompt_buckets=(16,))
+    reqs = [Request(uid=i, tokens=p, max_new_tokens=n) for i, (p, n) in
+            enumerate(zip(_prompts(arch, [4, 7, 3]), [3, 1, 2]))]
+    with mesh11:
+        report = engine.ServingEngine(params, ctx, cfg).run(reqs)
+    assert len(report.streams) == 3
+    for s in report.streams:
+        assert 0 < s.t_admitted <= s.t_first_token <= s.t_finished
+
+
 def test_serving_rejects_budget_overflow(mesh11, key):
     arch, ctx, params = _build(mesh11, key)
     cfg = engine.ServeConfig(num_slots=2, cache_len=16, prefill_pack=2,
