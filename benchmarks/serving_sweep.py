@@ -152,7 +152,9 @@ def run(quick: bool = False):
                                      prefill_pack=min(s, 4),
                                      prompt_buckets=(BUCKET,))
             with mesh:
-                eng = engine.ServingEngine(params, ctx, cfg)
+                # the engine consumes the params it is given
+                eng = engine.ServingEngine(
+                    jax.tree_util.tree_map(jnp.copy, params), ctx, cfg)
                 wall, report = _serve(eng, reqs[:s], rounds)
             total = report.total_new_tokens
             tps = total / wall

@@ -305,7 +305,7 @@ def serve_one_chip(arch, seed: int) -> dict:
     from repro import sharding
     from repro.launch.mesh import make_host_mesh
     from repro.models import decode as decode_lib, model as model_lib
-    from repro.serving import engine
+    from repro.serving import batching, engine
     from repro.serving.scheduler import Request
 
     mesh = make_host_mesh(data=1, model=1)
@@ -329,25 +329,25 @@ def serve_one_chip(arch, seed: int) -> dict:
 
     rules = model_lib.default_rules(mesh)
     with mesh, sharding.axis_rules(rules):
-        params = model_lib.init_params(jax.random.PRNGKey(seed), ctx,
-                                       rules=rules)
-        eng = engine.ServingEngine(params, ctx, cfg)
+        # the engine consumes the stacked params: inspect what it serves
+        eng = engine.ServingEngine(model_lib.init_params(
+            jax.random.PRNGKey(seed), ctx, rules=rules), ctx, cfg)
         c0 = _compile_seconds[0]
         warm = eng.run(requests(2, uid0=1000), seed=seed)
         compile_s = _compile_seconds[0] - c0
         reqs = requests(NUM_REQUESTS)
         report = eng.run(reqs, seed=seed)
-        cache = jax.eval_shape(lambda: decode_lib.init_cache(
-            ctx, cfg.num_slots, cfg.cache_len))
+        cache = jax.eval_shape(lambda: batching.SlotKVCache(
+            ctx, cfg.num_slots, cfg.cache_len,
+            per_layer=decode_lib.is_per_layer(eng.params)).cache)
         i32 = np.int32
-        prefill_calls = custom_calls(jax.jit(engine.make_prefill(
-            ctx, with_cache=True, cache_len=cfg.cache_len)).lower(
-            params, {"tokens": jax.ShapeDtypeStruct(
+        prefill_calls = custom_calls(eng._prefill.lower(
+            eng.params, {"tokens": jax.ShapeDtypeStruct(
                 (cfg.prefill_pack, PROMPT_LENS[1]), i32),
                 "lens": jax.ShapeDtypeStruct((cfg.prefill_pack,), i32)}
         ).compile())
-        decode_calls = custom_calls(jax.jit(engine.make_decode_step(
-            ctx)).lower(params, cache, jax.ShapeDtypeStruct(
+        decode_calls = custom_calls(eng._decode.lower(
+            eng.params, cache, jax.ShapeDtypeStruct(
                 (cfg.num_slots, 1), i32)).compile())
     served = sum(len(s.generated) for s in report.streams)
     log(f"serve: requests {len(reqs)} served {len(report.streams)} tokens "

@@ -1,8 +1,11 @@
 """Decode-time forward: one new token against per-layer caches/states.
 
-Cache pytree mirrors the (prefix, groups) layer plan; group caches carry a
-leading n_groups axis and thread through ``lax.scan`` alongside the stacked
-params.  Mixer-family cache kinds:
+Cache pytree mirrors the (prefix, groups) layer plan.  The groups come in
+one of two layouts (see ``unstack_groups``): stacked, with a leading
+n_groups axis, threaded through ``lax.scan`` alongside the stacked params;
+or per-layer, a tuple of per-group trees that a Python loop runs, each
+layer on its own buffers.  Params and cache come in one layout.
+Mixer-family cache kinds:
 
     attn  -> KV cache [B, L, K, hd]        (L may be sharded: context parallel)
     mla   -> compressed latent cache [B, L, r] + rope keys
@@ -14,8 +17,11 @@ params.  Mixer-family cache kinds:
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro import sharding
 from repro.models import layers, mamba as mamba_lib, mla as mla_lib
@@ -81,7 +87,14 @@ def fill_cross_cache(params, cache, enc_out, ctx: ModelCtx):
             cache[f"prefix{i}"]["cross_k"] = k.astype(a.dtype)
             cache[f"prefix{i}"]["cross_v"] = v.astype(a.dtype)
     for j, sub in enumerate(group):
-        if sub.cross:
+        if not sub.cross:
+            continue
+        if is_per_layer(params):
+            for pg, cg in zip(params["groups"], cache["groups"]):
+                k, v = kv(pg[f"sub{j}"]["cross"], stacked=False)
+                cg[f"sub{j}"]["cross_k"] = k.astype(a.dtype)
+                cg[f"sub{j}"]["cross_v"] = v.astype(a.dtype)
+        else:
             k, v = kv(params["groups"][f"sub{j}"]["cross"], stacked=True)
             cache["groups"][f"sub{j}"]["cross_k"] = k.astype(a.dtype)
             cache["groups"][f"sub{j}"]["cross_v"] = v.astype(a.dtype)
@@ -89,38 +102,121 @@ def fill_cross_cache(params, cache, enc_out, ctx: ModelCtx):
 
 
 # ---------------------------------------------------------------------------
-# slot-indexed cache ops (continuous-batching serving)
+# layer layouts and slot-indexed cache ops (continuous-batching serving)
 # ---------------------------------------------------------------------------
 #
-# The cache pytree has two batch layouts: top-level ``prefix{i}`` entries
-# carry the batch on axis 0, the stacked ``groups`` entry carries it on
-# axis 1 (axis 0 is the scanned layer-group axis).  The helpers below are
-# the only place that layout knowledge lives.
+# A params or cache tree holds its scanned layer groups under ``groups`` in
+# one of two layouts.  Stacked: every leaf carries a leading n_groups axis,
+# and ``lax.scan`` runs the groups (training, ``generate``, the dry-runs).
+# Per-layer: a tuple of n_groups per-group trees, every leaf its own
+# buffer, and a Python loop runs the groups (``ServingEngine`` for models
+# with MoE groups): a Pallas call cannot take a slice of a stack as its
+# operand, so a scanned step copies each layer's expert weights out of
+# their stacks first.  A call takes params and cache in one layout.
+# Top-level ``prefix{i}`` cache entries and per-layer group entries carry
+# the batch on axis 0, stacked group entries on axis 1.  The helpers below
+# are the only place that layout knowledge lives.
 
 
-def _map_batch_axis(cache, fn):
-    """Apply ``fn(leaf, batch_axis)`` across the cache pytree."""
-    out = {}
-    for k, v in cache.items():
-        axis = 1 if k == "groups" else 0
-        out[k] = jax.tree_util.tree_map(lambda leaf: fn(leaf, axis), v)
-    return out
+def is_per_layer(tree) -> bool:
+    return isinstance(tree["groups"], tuple)
+
+
+def _nbytes(a) -> int:
+    return math.prod(a.shape) * jnp.dtype(a.dtype).itemsize
+
+
+def unstack_groups(tree, *, donate: bool = False):
+    """``tree`` in the per-layer layout: its stacked ``groups`` entry as a
+    tuple of n_groups per-group trees (a per-layer tree is returned as it
+    is).  Leaves may be traced values, arrays (host arrays land on the
+    device) or ``jax.ShapeDtypeStruct`` shapes; arrays and shapes on a mesh
+    keep their stacked parent's sharding with the group axis dropped.
+
+    With ``donate``, each stacked device array is deleted as soon as its
+    slices exist, as a donated argument is: the leaves are split a few at a
+    time, at most the largest leaf's bytes at once, so the stacks and their
+    slices never sit whole side by side."""
+    groups = tree["groups"]
+    if isinstance(groups, tuple):
+        return tree
+    leaves, treedef = jax.tree_util.tree_flatten(groups)
+    n = leaves[0].shape[0]
+
+    def split(xs):
+        return [tuple(x[g] for g in range(n)) for x in xs]
+
+    if isinstance(leaves[0], jax.core.Tracer):
+        parts = split(leaves)
+    else:
+        # largest first, so each big leaf goes alone and the small ones
+        # share a program
+        order = sorted(range(len(leaves)), key=lambda i: -_nbytes(leaves[i]))
+        cap = _nbytes(leaves[order[0]])
+        chunks, size = [], cap
+        for i in order:
+            if size + _nbytes(leaves[i]) > cap:
+                chunks.append([])
+                size = 0
+            chunks[-1].append(i)
+            size += _nbytes(leaves[i])
+        parts = [None] * len(leaves)
+        for chunk in chunks:
+            xs = [leaves[i] for i in chunk]
+            out = None              # placed where the inputs are
+            if all(isinstance(getattr(x, "sharding", None), NamedSharding)
+                   for x in xs):
+                out = [(NamedSharding(x.sharding.mesh,
+                                      PartitionSpec(*x.sharding.spec[1:])),)
+                       * n for x in xs]
+            fn = jax.jit(split, out_shardings=out)
+            if isinstance(xs[0], jax.ShapeDtypeStruct):
+                got = fn.eval_shape(xs)
+            else:
+                got = fn(xs)
+                if donate:
+                    jax.block_until_ready(got)
+                    for x in xs:
+                        if isinstance(x, jax.Array):
+                            x.delete()
+            for i, g in zip(chunk, got):
+                parts[i] = g
+    return dict(tree, groups=tuple(
+        treedef.unflatten([p[g] for p in parts]) for g in range(n)))
+
+
+def stack_groups(tree):
+    """The inverse of ``unstack_groups``: ``tree`` in the stacked layout."""
+    groups = tree["groups"]
+    if not isinstance(groups, tuple):
+        return tree
+    return dict(tree, groups=jax.tree_util.tree_map(
+        lambda *xs: jnp.stack(xs), *groups))
+
+
+def batch_axis(key, entry) -> int:
+    """The batch axis of the leaves of a cache's top-level ``entry``."""
+    return 1 if key == "groups" and not isinstance(entry, tuple) else 0
+
+
+def _map_batch_axis(cache, fn, *others):
+    """``fn(leaf, *other_leaves, batch_axis)`` across the cache pytree;
+    ``others`` are trees of the same structure."""
+    return {k: jax.tree_util.tree_map(
+                lambda *xs, ax=batch_axis(k, v): fn(*xs, ax),
+                v, *(o[k] for o in others))
+            for k, v in cache.items()}
 
 
 def cache_insert_slots(dst, src, slots):
-    """Write ``src`` (leading batch P) into ``dst`` (leading batch N) at
-    ``slots`` [P].  Slot ids >= N are dropped (JAX scatter out-of-bounds
-    semantics), which is how padded admission packs no-op: pad ``slots``
-    with N and the extra rows never land."""
+    """Write ``src`` (leading batch P) into ``dst`` (leading batch N, the
+    same layer layout) at ``slots`` [P].  Slot ids >= N are dropped (JAX
+    scatter out-of-bounds semantics), which is how padded admission packs
+    no-op: pad ``slots`` with N and the extra rows never land."""
     def ins(d, s, axis):
         idx = (slice(None),) * axis + (slots,)
         return d.at[idx].set(s.astype(d.dtype))
-    out = {}
-    for k in dst:
-        axis = 1 if k == "groups" else 0
-        out[k] = jax.tree_util.tree_map(
-            lambda d, s: ins(d, s, axis), dst[k], src[k])
-    return out
+    return _map_batch_axis(dst, ins, src)
 
 
 def cache_evict_slots(cache, slots):
@@ -135,19 +231,14 @@ def cache_evict_slots(cache, slots):
 
 
 def _select_batch(mask, new, old):
-    """Per-request select between two cache pytrees: ``mask`` [B] picks
-    ``new`` where True.  Used by the scan prefill to freeze a request's
-    cache once its (right-padded) prompt is exhausted."""
+    """Per-request select between two cache pytrees of one layout: ``mask``
+    [B] picks ``new`` where True.  Used by the scan prefill to freeze a
+    request's cache once its (right-padded) prompt is exhausted."""
     def sel(n, o, axis):
         shape = [1] * n.ndim
         shape[axis] = mask.shape[0]
         return jnp.where(mask.reshape(shape), n, o)
-    out = {}
-    for k in new:
-        axis = 1 if k == "groups" else 0
-        out[k] = jax.tree_util.tree_map(
-            lambda n, o: sel(n, o, axis), new[k], old[k])
-    return out
+    return _map_batch_axis(new, sel, old)
 
 
 @jax.named_scope("layer")
@@ -189,10 +280,51 @@ def _decode_sublayer(p, c, x, sub: SubLayer, ctx: ModelCtx, layer_idx=None):
     return x, c
 
 
+def _group_loop(params, cache, x, ctx: ModelCtx, sublayer):
+    """Run the layer groups: ``sublayer(p, c, x, sub, layer_idx)`` returns
+    ``(x, c)``.  Per-layer params and cache run a Python loop over each
+    group's own buffers, and so do stacked ones under a dispatch override
+    that lands inside the groups (each layer then has its own dispatch);
+    stacked ones otherwise keep ``lax.scan``.  Returns ``(x, groups)``, the
+    new group caches in the layout they came in."""
+    prefix, group, n_groups = layer_plan(ctx.arch)
+    n_prefix = len(prefix)
+    per_layer = is_per_layer(params)
+    # ops of the loop itself, outside every sublayer's ``layer`` scope, are
+    # ``layer.params``: under ``lax.scan``, the slices of each layer's
+    # parameters and cache out of their stacks and the cache written back
+    with jax.named_scope("layer.params"):
+        if per_layer or _overrides_hit_groups(
+                ctx, n_prefix, group, n_groups, decode=True):
+            ps = unstack_groups(params)["groups"]
+            new = []
+            for g, c in enumerate(unstack_groups(cache)["groups"]):
+                c = dict(c)
+                for j, sub in enumerate(group):
+                    x, c[f"sub{j}"] = sublayer(
+                        ps[g][f"sub{j}"], dict(c[f"sub{j}"]), x, sub,
+                        n_prefix + g * len(group) + j)
+                new.append(c)
+            groups = tuple(new)
+            if not per_layer:
+                groups = stack_groups({"groups": groups})["groups"]
+            return x, groups
+
+        def body(x, pc):
+            p, c = pc
+            c = dict(c)
+            for j, sub in enumerate(group):
+                x, c[f"sub{j}"] = sublayer(
+                    p[f"sub{j}"], dict(c[f"sub{j}"]), x, sub, None)
+            return x, c
+
+        return jax.lax.scan(body, x, (params["groups"], cache["groups"]))
+
+
 def decode_step(params, cache, tokens, ctx: ModelCtx):
     """tokens: [B, 1] — returns (logits [B, 1, V], new_cache)."""
     a = ctx.arch
-    prefix, group, n_groups = layer_plan(a)
+    prefix = layer_plan(a)[0]
     x = layers.embed_apply(params["embed"], tokens)
     if not ctx.decode_replicated:
         x = sharding.constrain(x, "batch", None, None)
@@ -203,39 +335,10 @@ def decode_step(params, cache, tokens, ctx: ModelCtx):
             params[f"prefix{i}"], dict(cache[f"prefix{i}"]), x, sub, ctx,
             layer_idx=i)
 
-    n_prefix = len(prefix)
-    # the layer loop slices each layer's parameters (and cache) out of their
-    # stacks; that work, outside every sublayer's ``layer`` scope, is
-    # ``layer.params``
-    with jax.named_scope("layer.params"):
-        if _overrides_hit_groups(ctx, n_prefix, group, n_groups, decode=True):
-            # layer-dependent dispatch inside the groups: unroll (mirrors
-            # transformer.forward_features) and restack the per-group caches.
-            new_gs = []
-            for g in range(n_groups):
-                pg = jax.tree_util.tree_map(lambda a, g=g: a[g],
-                                            params["groups"])
-                cg = jax.tree_util.tree_map(lambda a, g=g: a[g],
-                                            cache["groups"])
-                for j, sub in enumerate(group):
-                    x, cg[f"sub{j}"] = _decode_sublayer(
-                        pg[f"sub{j}"], dict(cg[f"sub{j}"]), x, sub, ctx,
-                        layer_idx=n_prefix + g * len(group) + j)
-                new_gs.append(cg)
-            new_cache["groups"] = jax.tree_util.tree_map(
-                lambda *xs: jnp.stack(xs), *new_gs)
-        else:
-            def body(x, pc):
-                p, c = pc
-                c = jax.tree_util.tree_map(lambda v: v, c)  # shallow copy
-                for j, sub in enumerate(group):
-                    x, c[f"sub{j}"] = _decode_sublayer(
-                        p[f"sub{j}"], dict(c[f"sub{j}"]), x, sub, ctx)
-                return x, c
-
-            x, new_groups = jax.lax.scan(body, x, (params["groups"],
-                                                   cache["groups"]))
-            new_cache["groups"] = new_groups
+    x, new_cache["groups"] = _group_loop(
+        params, cache, x, ctx,
+        lambda p, c, x, sub, i: _decode_sublayer(p, c, x, sub, ctx,
+                                                 layer_idx=i))
     x = layers.norm_apply(params["final_norm"], x, a.norm)
     logits = layers.unembed_apply(params["embed"], x)
     return logits, new_cache
@@ -349,6 +452,8 @@ def prefill(params, batch, ctx: ModelCtx, *, cache_len: int, lens=None):
         lens = jnp.full((B,), S, jnp.int32)
     lens = jnp.asarray(lens, jnp.int32)
     cache = init_cache(ctx, B, cache_len)
+    if is_per_layer(params):
+        cache = unstack_groups(cache)
 
     if a.family == "audio" and "frontend" in batch:
         from repro.models.transformer import _run_encoder
@@ -359,7 +464,7 @@ def prefill(params, batch, ctx: ModelCtx, *, cache_len: int, lens=None):
     if _needs_scan_prefill(a):
         return _prefill_by_scan(params, batch, cache, ctx, lens)
 
-    prefix, group, n_groups = layer_plan(a)
+    prefix = layer_plan(a)[0]
     x = layers.embed_apply(params["embed"], tokens)
     if a.family == "vlm" and "frontend" in batch:
         patches = jax.nn.gelu(batch["frontend"].astype(x.dtype)
@@ -375,37 +480,10 @@ def prefill(params, batch, ctx: ModelCtx, *, cache_len: int, lens=None):
             params[f"prefix{i}"], dict(cache[f"prefix{i}"]), x, sub, ctx,
             lens, layer_idx=i)
 
-    n_prefix = len(prefix)
-    # the layer loop slices each layer's parameters (and cache) out of their
-    # stacks; that work, outside every sublayer's ``layer`` scope, is
-    # ``layer.params``
-    with jax.named_scope("layer.params"):
-        if _overrides_hit_groups(ctx, n_prefix, group, n_groups, decode=True):
-            new_gs = []
-            for g in range(n_groups):
-                pg = jax.tree_util.tree_map(lambda a, g=g: a[g],
-                                            params["groups"])
-                cg = jax.tree_util.tree_map(lambda a, g=g: a[g],
-                                            cache["groups"])
-                for j, sub in enumerate(group):
-                    x, cg[f"sub{j}"] = _prefill_sublayer(
-                        pg[f"sub{j}"], dict(cg[f"sub{j}"]), x, sub, ctx, lens,
-                        layer_idx=n_prefix + g * len(group) + j)
-                new_gs.append(cg)
-            new_cache["groups"] = jax.tree_util.tree_map(
-                lambda *xs: jnp.stack(xs), *new_gs)
-        else:
-            def body(x, pc):
-                p, c = pc
-                c = jax.tree_util.tree_map(lambda v: v, c)  # shallow copy
-                for j, sub in enumerate(group):
-                    x, c[f"sub{j}"] = _prefill_sublayer(
-                        p[f"sub{j}"], dict(c[f"sub{j}"]), x, sub, ctx, lens)
-                return x, c
-
-            x, new_groups = jax.lax.scan(body, x, (params["groups"],
-                                                   cache["groups"]))
-            new_cache["groups"] = new_groups
+    x, new_cache["groups"] = _group_loop(
+        params, cache, x, ctx,
+        lambda p, c, x, sub, i: _prefill_sublayer(p, c, x, sub, ctx, lens,
+                                                  layer_idx=i))
 
     x = layers.norm_apply(params["final_norm"], x, a.norm)
     logits = layers.unembed_apply(params["embed"], x)

@@ -65,20 +65,25 @@ def pad_frontend_pack(frontends, pack: int):
 
 
 class SlotKVCache:
-    """A decode cache with ``num_slots`` batch rows managed as slots.
+    """A decode cache with ``num_slots`` batch rows managed as slots, its
+    layer groups stacked or, with ``per_layer``, one buffer per layer
+    (``decode_lib.unstack_groups``): the layout of the params it serves.
 
     All three operations are jitted once and reused for the engine's
     lifetime — slot ids are data, not shapes — so admit/evict/re-admit
-    cycles never recompile.
+    cycles never recompile.  ``insert`` and ``evict`` donate the cache they
+    replace, so each writes its slots in place: hold ``self.cache``, never
+    an earlier value of it.
     """
 
-    def __init__(self, ctx, num_slots: int, cache_len: int):
+    def __init__(self, ctx, num_slots: int, cache_len: int, *,
+                 per_layer: bool = False):
         self.ctx = ctx
         self.num_slots = int(num_slots)
         self.cache_len = int(cache_len)
-        self.cache = jax.jit(
-            lambda: decode_lib.init_cache(ctx, self.num_slots,
-                                          self.cache_len))()
+        layout = decode_lib.unstack_groups if per_layer else (lambda c: c)
+        self.cache = jax.jit(lambda: layout(
+            decode_lib.init_cache(ctx, self.num_slots, self.cache_len)))()
         if getattr(ctx, "mesh", None) is not None:
             # match the NamedSharding that prefilled pack caches carry, so
             # the very first insert hits the same jit entry as every later
@@ -90,8 +95,10 @@ class SlotKVCache:
         # jax.jit shares its cache across wrappers of the same callable, so
         # another engine's differently-shaped cache would otherwise leak
         # into this instance's cache stats
-        self._insert = jax.jit(functools.partial(decode_lib.cache_insert_slots))
-        self._evict = jax.jit(functools.partial(decode_lib.cache_evict_slots))
+        self._insert = jax.jit(
+            functools.partial(decode_lib.cache_insert_slots), donate_argnums=0)
+        self._evict = jax.jit(
+            functools.partial(decode_lib.cache_evict_slots), donate_argnums=0)
 
     def insert(self, src_cache, slot_ids) -> None:
         """Write a prefilled pack cache into ``slot_ids`` (out-of-range ids
@@ -113,5 +120,6 @@ class SlotKVCache:
                       if str(getattr(path[-1], "key", "")) == "pos"]
             if leaves:
                 pos = leaves[0]
-                return np.asarray(pos[0] if k == "groups" else pos)
+                return np.asarray(
+                    pos[0] if decode_lib.batch_axis(k, self.cache[k]) else pos)
         raise ValueError("cache has no pos leaf (recurrent-only family)")

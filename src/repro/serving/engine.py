@@ -228,6 +228,15 @@ class ServingReport:
         raise KeyError(uid)
 
 
+def _moe_groups(arch) -> bool:
+    """Whether ``arch``'s layer groups hold an MoE sub-layer.  A scanned
+    step copies each such layer's expert stacks out for the Pallas expert
+    kernel, which cannot take a slice of a stack as its operand, so the
+    engine serves these models per layer; other models keep the scan,
+    whose program does not grow with depth."""
+    return any(sub.ffn == "moe" for sub in transformer.layer_plan(arch)[1])
+
+
 class ServingEngine:
     """Slot-based continuous batching over the MoE decode path.
 
@@ -237,10 +246,20 @@ class ServingEngine:
     free slots and prefill them as one fused pack, (2) advance every slot
     one decode step, (3) complete streams that hit their budget, freeing
     their slots for the next admission round.
+
+    A model whose layer groups hold an MoE sub-layer is served from one
+    buffer per layer (``decode_lib.unstack_groups``): the engine consumes
+    stacked ``params``, deleting each stacked group array once its
+    per-layer slices exist, so the weights are never held twice.  A caller
+    that goes on using ``params`` passes ``unstack_groups(params)``, which
+    the engine takes as it is.  The decode program donates the slot cache:
+    a cache handed to it is consumed, and the one it returns replaces it.
     """
 
     def __init__(self, params, ctx: transformer.ModelCtx, cfg: ServeConfig,
                  dispatch_override=None):
+        if _moe_groups(ctx.arch):
+            params = decode_lib.unstack_groups(params, donate=True)
         self.params = params
         self.ctx = _with_overrides(ctx, dispatch_override)
         self.cfg = cfg
@@ -248,7 +267,7 @@ class ServingEngine:
             raise ValueError("prompt bucket exceeds cache_len")
         self._prefill = jax.jit(make_prefill(
             self.ctx, with_cache=True, cache_len=cfg.cache_len))
-        self._decode = jax.jit(make_decode_step(self.ctx))
+        self._decode = jax.jit(make_decode_step(self.ctx), donate_argnums=1)
         self._sample = jax.jit(_make_sample())
         # current token per slot, scatter-updated at admission; padded pack
         # rows carry slot id == num_slots and are dropped (OOB scatter)
@@ -311,7 +330,9 @@ class ServingEngine:
         sched = Scheduler(cfg.num_slots)
         for req in requests:
             sched.submit(req)
-        kv = batching.SlotKVCache(self.ctx, cfg.num_slots, cfg.cache_len)
+        kv = batching.SlotKVCache(
+            self.ctx, cfg.num_slots, cfg.cache_len,
+            per_layer=decode_lib.is_per_layer(self.params))
         cur = jnp.zeros((cfg.num_slots, 1), jnp.int32)
         temps = np.zeros((cfg.num_slots,), np.float32)
         key = jax.random.PRNGKey(seed)

@@ -17,7 +17,7 @@ import pytest
 
 from repro import sharding
 from repro.configs.base import get_config
-from repro.models import model as model_lib
+from repro.models import decode as decode_lib, model as model_lib
 from repro.serving import batching, engine
 from repro.serving.scheduler import Request, Scheduler
 
@@ -203,7 +203,9 @@ def test_batched_generate_parity_with_single_stream(mesh11, key):
     cfg = engine.ServeConfig(num_slots=4, cache_len=24, prefill_pack=2,
                              prompt_buckets=(16,))
     with mesh11:
-        eng = engine.ServingEngine(params, ctx, cfg)
+        # the engine consumes stacked params; ``generate`` below reads them
+        eng = engine.ServingEngine(decode_lib.unstack_groups(params), ctx,
+                                   cfg)
         reqs = [Request(uid=i, tokens=p, max_new_tokens=steps)
                 for i, p in enumerate(prompts)]
         report = eng.run(reqs)
@@ -254,6 +256,74 @@ def test_streams_stamp_their_first_token(mesh11, key):
     assert len(report.streams) == 3
     for s in report.streams:
         assert 0 < s.t_admitted <= s.t_first_token <= s.t_finished
+
+
+def test_engine_programs_take_per_layer_buffers(mesh11, key):
+    """A model with MoE groups: the engine consumes the stacked params (each
+    group stack deleted once split), its decode and prefill programs take
+    each layer's weights and cache as buffers of their own (no argument has
+    the stacks' leading layer-group axis), and decode, insert and evict
+    donate the slot cache."""
+    arch = dataclasses.replace(get_config(ARCH_ID).reduced(), num_layers=6,
+                               dtype="float32")
+    ctx = model_lib.build_ctx(arch, mesh11, seq_len=24, global_batch=2,
+                              aux_mode="none")
+    cfg = engine.ServeConfig(num_slots=2, cache_len=24, prefill_pack=2,
+                             prompt_buckets=(16,))
+    with mesh11, sharding.axis_rules(model_lib.default_rules(mesh11)):
+        params = model_lib.init_params(key, ctx)
+        stacks = jax.tree_util.tree_leaves(
+            (params["groups"], decode_lib.init_cache(ctx, 2, 24)["groups"]))
+        eng = engine.ServingEngine(params, ctx, cfg)
+        kv = batching.SlotKVCache(ctx, cfg.num_slots, cfg.cache_len,
+                                  per_layer=True)
+        tokens, lens = batching.pad_pack([[1, 2, 3]], cfg.prefill_pack,
+                                         cfg.prompt_buckets)
+        prefill = eng._prefill.lower(eng.params,
+                                     {"tokens": tokens, "lens": lens})
+        cur = jnp.zeros((cfg.num_slots, 1), jnp.int32)
+        decode = eng._decode.lower(eng.params, kv.cache, cur)
+        _, pack = eng._prefill(eng.params, {"tokens": tokens, "lens": lens})
+        slots = jnp.asarray([0, cfg.num_slots], jnp.int32)
+        insert = kv._insert.lower(kv.cache, pack, slots)
+        evict = kv._evict.lower(kv.cache, slots)
+    assert isinstance(eng.params["groups"], tuple)
+    assert all(a.is_deleted()
+               for a in jax.tree_util.tree_leaves(params["groups"]))
+    assert not params["embed"]["table"].is_deleted()
+    assert stacks[0].shape[0] == 3
+    stacked = {leaf.shape for leaf in stacks}
+    for program in (prefill, decode, insert):
+        shapes = [a.shape for a in jax.tree_util.tree_leaves(
+            program.args_info)]
+        assert not stacked & set(shapes), stacked & set(shapes)
+    (p_info, c_info, t_info), _ = decode.args_info
+    assert all(a.donated for a in jax.tree_util.tree_leaves(c_info))
+    assert not any(a.donated for a in jax.tree_util.tree_leaves(
+        (p_info, t_info)))
+    assert "tf.aliasing_output" in decode.as_text()
+    for program in (insert, evict):
+        cache_info = program.args_info[0][0]
+        assert all(a.donated for a in jax.tree_util.tree_leaves(cache_info))
+
+
+def test_engine_keeps_the_scan_without_moe_groups(mesh11, key):
+    """A model without MoE groups is served from its stacked params, which
+    the engine leaves as they are, and still matches ``generate``."""
+    arch, ctx, params = _build(mesh11, key, arch_id="olmo_1b")
+    cfg = engine.ServeConfig(num_slots=2, cache_len=24, prefill_pack=2,
+                             prompt_buckets=(16,))
+    prompts = _prompts(arch, [5, 8], seed=4)
+    with mesh11:
+        eng = engine.ServingEngine(params, ctx, cfg)
+        assert eng.params is params
+        report = eng.run([Request(uid=i, tokens=p, max_new_tokens=3)
+                          for i, p in enumerate(prompts)])
+        for i, p in enumerate(prompts):
+            single = engine.generate(
+                params, ctx, jnp.asarray(np.asarray(p, np.int32)[None]),
+                steps=3, cache_len=24)
+            assert report.tokens_for(i) == list(np.asarray(single.tokens[0]))
 
 
 def test_serving_rejects_budget_overflow(mesh11, key):
