@@ -8,7 +8,8 @@ schema :data:`METRIC_KEYS` — missing keys are filled with neutral defaults
 by the engine so callers (shard_map out_specs, trainers, benchmarks) never
 branch on the path.  ``frac_by_level`` is a fixed-length ``[num_stages]``
 vector (one entry per dispatch stage of the EP hierarchy, stage 0 folding
-in the self level).
+in the self level); ``frac_self`` is the share of assignments that stay on
+the rank's own experts, the self level that stage 0 folds in.
 
 Every path names its parts with ``jax.named_scope`` -- ``moe.route``
 (gate, top-k, aux loss, dispatch metrics), ``moe.dispatch`` (index
@@ -56,7 +57,7 @@ from repro.kernels.moe_permute import ops as permute_ops
 
 #: Uniform metrics schema every path resolves to.  ``frac_by_level`` is a
 #: ``[num_stages]`` vector.
-METRIC_KEYS = ("aux_loss", "frac_by_level", "dropped")
+METRIC_KEYS = ("aux_loss", "frac_by_level", "dropped", "frac_self")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -133,7 +134,10 @@ class DispatchEngine:
         out = {"aux_loss": metrics["aux_loss"],
                "frac_by_level": fb,
                "dropped": jnp.asarray(metrics.get("dropped", 0.0),
-                                      jnp.float32)}
+                                      jnp.float32),
+               # neutral default: every expert is this rank's own
+               "frac_self": jnp.asarray(metrics.get("frac_self", 1.0),
+                                        jnp.float32)}
         return y, out
 
 
@@ -355,6 +359,7 @@ def _staged_a2a(params, x, eng: DispatchEngine, num_chunks: int):
                                                   plan.num_stages),
             "dropped": 1.0 - jnp.minimum(
                 kept_unpadded / (T * gate_cfg.top_k), 1.0),
+            "frac_self": gating.frac_self(frac, routed.levels),
         }
     return out.astype(x.dtype), metrics
 
@@ -454,7 +459,8 @@ def _gather_path(params, x, eng: DispatchEngine):
                                          cfg.num_experts)
         fb = gating.frac_by_level(frac, levels, eng.num_stages)
     metrics = {"aux_loss": aux, "frac_by_level": fb,
-               "dropped": 0.0}   # no capacity limit: nothing ever drops
+               "dropped": 0.0,   # no capacity limit: nothing ever drops
+               "frac_self": gating.frac_self(frac, levels)}
     return y.astype(x.dtype), metrics
 
 

@@ -44,9 +44,15 @@ from repro.core.dispatch import wire as wire_lib
 from repro.core.dispatch.base import EPSpec
 
 
+#: Named scope of every all_to_all of the exchange (dispatch, counts,
+#: combine), so a profile tells the exchange from the permute around it.
+A2A_SCOPE = "moe.a2a"
+
+
 def _a2a(x, axis_name, *, split_axis, concat_axis):
-    return jax.lax.all_to_all(x, axis_name, split_axis=split_axis,
-                              concat_axis=concat_axis, tiled=True)
+    with jax.named_scope(A2A_SCOPE):
+        return jax.lax.all_to_all(x, axis_name, split_axis=split_axis,
+                                  concat_axis=concat_axis, tiled=True)
 
 
 def wire_a2a(x, axis_name, *, split_axis, concat_axis, wire_dtype: str = ""):
@@ -277,8 +283,7 @@ class A2ATransport:
         GEMM size its compute by realized tokens."""
         k = len(stage.axis_names)
         for i, ax in enumerate(stage.axis_names):
-            cnt = jax.lax.all_to_all(cnt, ax, split_axis=i, concat_axis=i,
-                                     tiled=True)
+            cnt = _a2a(cnt, ax, split_axis=i, concat_axis=i)
         perm = (k,) + tuple(range(k))
         return cnt.transpose(perm).reshape(cnt.shape[k], stage.num_dests)
 

@@ -116,6 +116,12 @@ def frac_by_level(frac, levels, num_stages: int) -> jnp.ndarray:
     return jnp.einsum("ns,n->s", onehot, frac.astype(jnp.float32))
 
 
+def frac_self(frac, levels) -> jnp.ndarray:
+    """Share of the assignments routed to this rank's own experts (level
+    0), which :func:`frac_by_level` folds into stage 0."""
+    return jnp.sum(jnp.where(levels == 0, frac.astype(jnp.float32), 0.0))
+
+
 def aux_loss(gate_out, cfg: GateConfig,
              levels: jnp.ndarray | None = None) -> jnp.ndarray:
     """Auxiliary loss for this shard's tokens.

@@ -126,6 +126,15 @@ class ModelCtx:
             wire_codec=self.wire_codec)
 
     @property
+    def traffic_shares(self) -> tuple:
+        """The dispatch metrics the layers average and the loss reports:
+        ``frac_by_level``, and ``frac_self`` where the experts span more
+        than one device (on one device every expert is the rank's own)."""
+        if self.ep is not None and self.ep.ep_world > 1:
+            return ("frac_by_level", "frac_self")
+        return ("frac_by_level",)
+
+    @property
     def frac_levels(self) -> int:
         """Length of the ``frac_by_level`` metric vector (dispatch stages
         of the EP hierarchy; 1 when the model has no MoE layers)."""
@@ -347,9 +356,10 @@ def _merge_specs(params, partial_specs):
 def _apply_sublayer(p, x, sub: SubLayer, ctx: ModelCtx, *, enc_out=None,
                     aux0=0.0, frac0=None, drop0=None, layer_idx=None):
     """Returns (x, aux, frac, drop): the residual stream, the accumulated
-    aux loss, the accumulated per-level dispatch-fraction vector, and the
-    accumulated dropped-token fraction (``frac0`` / ``drop0`` passed
-    through unchanged — possibly None — for non-MoE sublayers)."""
+    aux loss, the accumulated traffic shares (a dict keyed by
+    ``ctx.traffic_shares``), and the accumulated dropped-token fraction
+    (``frac0`` / ``drop0`` passed through unchanged — possibly None — for
+    non-MoE sublayers)."""
     a = ctx.arch
     h = layers.norm_apply(p["norm1"], x, a.norm)
     if sub.mixer == "attn":
@@ -384,7 +394,7 @@ def _apply_sublayer(p, x, sub: SubLayer, ctx: ModelCtx, *, enc_out=None,
         x = x + y
         aux = aux + metrics["aux_loss"]
         if frac is not None:
-            frac = frac + metrics["frac_by_level"]
+            frac = {k: v + metrics[k] for k, v in frac.items()}
         if drop is not None:
             drop = drop + metrics["dropped"]
     x = sharding.constrain(x, "batch", None, None)
@@ -436,11 +446,12 @@ def _overrides_hit_groups(ctx: ModelCtx, n_prefix: int, group, n_groups: int,
 def forward_features(params, batch, ctx: ModelCtx):
     """Full-sequence forward up to the final norm.
 
-    Returns ``(x, aux, frac_by_level, dropped)``: features, the mean aux
-    loss, the mean per-level dispatch-fraction vector over the MoE layers,
-    and the mean dropped-token fraction (the engine's uniform ``dropped``
-    metric — the step-health watermark reads it).  The latter two are None
-    for models without MoE layers.
+    Returns ``(x, aux, shares, dropped)``: features, the mean aux loss, the
+    mean traffic shares over the MoE layers (a dict: the per-level
+    dispatch-fraction vector ``frac_by_level`` and, across devices,
+    ``frac_self``), and the mean dropped-token fraction (the engine's
+    uniform ``dropped`` metric — the step-health watermark reads it).  The
+    latter two are None for models without MoE layers.
     """
     a = ctx.arch
     prefix, group, n_groups = layer_plan(a)
@@ -459,7 +470,10 @@ def forward_features(params, batch, ctx: ModelCtx):
 
     aux = jnp.float32(0.0)
     n_moe = n_groups * sum(1 for s in group if s.ffn == "moe")
-    frac = jnp.zeros((ctx.frac_levels,), jnp.float32) if n_moe else None
+    frac = None
+    if n_moe:
+        frac = {k: jnp.float32(0.0) for k in ctx.traffic_shares}
+        frac["frac_by_level"] = jnp.zeros((ctx.frac_levels,), jnp.float32)
     drop = jnp.float32(0.0) if n_moe else None
     for i, sub in enumerate(prefix):
         x, aux, frac, drop = _apply_sublayer(
@@ -506,7 +520,7 @@ def forward_features(params, batch, ctx: ModelCtx):
 
     x = layers.norm_apply(params["final_norm"], x, a.norm)
     if frac is not None:
-        frac = frac / max(1, n_moe)
+        frac = {k: v / max(1, n_moe) for k, v in frac.items()}
     if drop is not None:
         drop = drop / max(1, n_moe)
     return x, aux / max(1, n_groups * len(group)), frac, drop
@@ -557,8 +571,9 @@ def loss_fn(params, batch, ctx: ModelCtx, aux_weight: float = 1.0):
     total = nll + aux_weight * aux
     metrics = {"nll": nll, "aux": aux, "loss": total}
     if frac is not None:
-        # mean per-level dispatch fractions over the MoE layers
-        metrics["frac_by_level"] = frac
+        # mean per-level dispatch fractions (and, across devices, the self
+        # share) over the MoE layers
+        metrics.update(frac)
     if drop is not None:
         # mean dropped-assignment fraction over the MoE layers (the
         # engine's uniform `dropped` metric) — feeds the step-health
